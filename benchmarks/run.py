@@ -67,7 +67,7 @@ def bench_backends(log=print):
     from repro.core import matmul as mm
     from repro.core.matmul import gather_blocks, scatter_blocks
     from repro.dist.mesh import dragonfly_layout
-    from repro.runtime import compat, lowering
+    from repro.runtime import lowering
     from repro.runtime.backends.jax_ppermute import JaxPpermuteBackend
     from repro.runtime.backends.reference import NumpyReferenceBackend
 
@@ -117,10 +117,10 @@ def bench_backends(log=print):
     jaxbe = JaxPpermuteBackend()
     mesh = Mesh(np.array(jax.devices()[:n]), ("df",))
     xj = jnp.asarray(x)
-    run_df = jax.jit(compat.shard_map(
+    run_df = jax.jit(jax.shard_map(
         lambda s: jaxbe.alltoall(s[0], "df", prog)[None],
         mesh=mesh, in_specs=P("df"), out_specs=P("df")))
-    run_xla = jax.jit(compat.shard_map(
+    run_xla = jax.jit(jax.shard_map(
         lambda s: jax.lax.all_to_all(s[0], "df", split_axis=0, concat_axis=0)[None],
         mesh=mesh, in_specs=P("df"), out_specs=P("df")))
     _, us = _timed(lambda: run_df(xj).block_until_ready())
@@ -130,7 +130,7 @@ def bench_backends(log=print):
 
     bb = jnp.asarray(scatter_blocks(g, B))
     aa = jnp.asarray(scatter_blocks(g, A))
-    run_mm = jax.jit(compat.shard_map(
+    run_mm = jax.jit(jax.shard_map(
         lambda p, q: jaxbe.matmul(p[0], q[0], "df", mprog)[None],
         mesh=mesh, in_specs=(P("df"), P("df")), out_specs=P("df")))
     out, us = _timed(lambda: run_mm(bb, aa).block_until_ready())
@@ -443,11 +443,8 @@ def bench_autotuner(log=print):
         )
         times: dict[str, float] = {}
         for s in at.candidates(kind, site):
-            try:
-                fn = at._measure_closure(kind, site, s, layout, grid,
-                                         dec.key.nbytes, dec.key.dtype)
-            except Exception:
-                fn = None
+            fn = at._measure_closure(kind, site, s, layout, grid,
+                                     dec.key.nbytes, dec.key.dtype)
             if fn is None:
                 log(f"autotuner_strategy,kind={kind},site={site},n={n},"
                     f"b={dec.key.nbytes},strategy={s},skipped=unrunnable_here")
@@ -583,7 +580,6 @@ def bench_moe_pipeline(log=print):
 
     from repro.dist.collectives import alltoall_program
     from repro.dist.mesh import dragonfly_layout
-    from repro.runtime import compat
     from repro.runtime.backends.jax_ppermute import JaxPpermuteBackend
     from repro.runtime.backends.reference import NumpyReferenceBackend
 
@@ -606,7 +602,7 @@ def bench_moe_pipeline(log=print):
     log(f"moe_pipeline,path=reference,{tag},oracle=1")
 
     mesh = Mesh(np.array(jax.devices()[:n]), ("df",))
-    sm = lambda body: jax.jit(compat.shard_map(
+    sm = lambda body: jax.jit(jax.shard_map(
         body, mesh=mesh, in_specs=P("df"), out_specs=P("df")))
     be_loop = JaxPpermuteBackend()
     be_of = JaxPpermuteBackend(overlap_fused=True)
@@ -914,6 +910,9 @@ def main(argv=None) -> None:
         with open(args.json, "a"):
             pass
 
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     records: list[dict] = []
 
     def log(line):

@@ -281,7 +281,8 @@ def dragonfly_all_to_all_compute(x, axis_name: str, layout: DeviceLayout,
     contracted). ``compute`` is THIS shard's batched chunk transform
     (called with the (V, ...) stack of one wave's arrivals — close it over
     the shard's weights); ``offset`` picks the launch schedule. Bit-exact
-    vs the sequential three-step form for chunk-batchable ``compute``.
+    vs the sequential three-step form when ``compute`` gives the same bits
+    on a wave's stack as on the whole batch (a batched einsum may not).
 
     With an ``embedding``, ``layout`` is the guest and the round trip runs
     on the host mesh axis; idle devices contribute nothing and their rows

@@ -10,6 +10,11 @@ loop walks kv tiles of size bk with running max/denominator (the
 standard flash recurrence), skipping fully-masked tiles (causal upper
 triangle / outside the sliding window) via the grid mask, all in VMEM:
 q tile (bq, D) + k/v tiles (bk, D) + acc (bq, D) — a few hundred KB.
+
+The kernel is forward-only. ``flash_attention`` carries a ``custom_vjp``
+whose backward recomputes the same attention with the pure-XLA
+``flash_attention_xla`` and takes its VJP, so training steps can use the
+kernel in the forward pass.
 """
 
 from __future__ import annotations
@@ -21,10 +26,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# pallas renamed TPUCompilerParams -> CompilerParams across jax releases
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-
-
+from repro.kernels.flash_attention.xla_flash import flash_attention_xla
 
 NEG_INF = -1e30
 
@@ -77,30 +79,9 @@ def _flash_kernel(
         o_ref[0] = (acc_scr[...] / l).astype(o_ref.dtype)
 
 
-@functools.partial(
-    jax.jit,
-    static_argnames=("causal", "window", "bq", "bk", "interpret", "scale"),
-)
-def flash_attention(
-    q: jax.Array,
-    k: jax.Array,
-    v: jax.Array,
-    *,
-    causal: bool = True,
-    window: int | None = None,
-    scale: float | None = None,
-    bq: int = 256,
-    bk: int = 256,
-    interpret: bool = False,
-) -> jax.Array:
-    """q: (BH, Sq, D), k/v: (BH, Sk, D) -> (BH, Sq, D)."""
+def _flash_call(q, k, v, causal, window, scale, bq, bk, interpret):
     BH, Sq, D = q.shape
     _, Sk, _ = k.shape
-    bq = min(bq, Sq)
-    bk = min(bk, Sk)
-    assert Sq % bq == 0 and Sk % bk == 0, ((Sq, Sk), (bq, bk))
-    if scale is None:
-        scale = 1.0 / (D ** 0.5)
     n_k = Sk // bk
     grid = (BH, Sq // bq, n_k)
     return pl.pallas_call(
@@ -121,8 +102,61 @@ def flash_attention(
             pltpu.VMEM((bq, 1), jnp.float32),
             pltpu.VMEM((bq, D), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=(pltpu.PARALLEL, pltpu.PARALLEL, pltpu.ARBITRARY),
         ),
         interpret=interpret,
     )(q, k, v)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
+def _flash(q, k, v, causal, window, scale, bq, bk, interpret):
+    return _flash_call(q, k, v, causal, window, scale, bq, bk, interpret)
+
+
+def _flash_fwd(q, k, v, causal, window, scale, bq, bk, interpret):
+    return _flash_call(q, k, v, causal, window, scale, bq, bk, interpret), (q, k, v)
+
+
+def _flash_bwd(causal, window, scale, bq, bk, interpret, res, g):
+    """Backward: recompute the attention with ``flash_attention_xla`` (same
+    mask and scale; (BH, S, D) viewed as one batch of BH heads) and apply
+    its VJP to the incoming cotangent."""
+
+    def xla(q, k, v):
+        return flash_attention_xla(
+            q[None], k[None], v[None], causal=causal, window=window, scale=scale,
+        )[0]
+
+    _, vjp = jax.vjp(xla, *res)
+    return vjp(g)
+
+
+_flash.defvjp(_flash_fwd, _flash_bwd)
+
+
+@functools.partial(
+    jax.jit,
+    static_argnames=("causal", "window", "bq", "bk", "interpret", "scale"),
+)
+def flash_attention(
+    q: jax.Array,
+    k: jax.Array,
+    v: jax.Array,
+    *,
+    causal: bool = True,
+    window: int | None = None,
+    scale: float | None = None,
+    bq: int = 256,
+    bk: int = 256,
+    interpret: bool = False,
+) -> jax.Array:
+    """q: (BH, Sq, D), k/v: (BH, Sk, D) -> (BH, Sq, D). Differentiable."""
+    _, Sq, D = q.shape
+    Sk = k.shape[1]
+    bq = min(bq, Sq)
+    bk = min(bk, Sk)
+    assert Sq % bq == 0 and Sk % bk == 0, ((Sq, Sk), (bq, bk))
+    if scale is None:
+        scale = 1.0 / (D ** 0.5)
+    return _flash(q, k, v, causal, window, scale, bq, bk, interpret)

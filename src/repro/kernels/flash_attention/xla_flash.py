@@ -80,7 +80,8 @@ def flash_attention_xla(
             jnp.zeros((B, H, bq), jnp.float32),
             jnp.zeros((B, H, bq, D), jnp.float32),
         )
-        (m, l, acc), _ = jax.lax.scan(kv_step, init, (jnp.arange(nk), kc, vc))
+        (m, l, acc), _ = jax.lax.scan(
+            jax.checkpoint(kv_step), init, (jnp.arange(nk), kc, vc))
         l = jnp.where(l == 0.0, 1.0, l)
         return (acc / l[..., None]).astype(q.dtype)
 

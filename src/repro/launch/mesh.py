@@ -20,13 +20,12 @@ import jax
 
 from repro.dist.mesh import DeviceLayout, dragonfly_layout
 from repro.dist.sharding import ShardRules
-from repro.runtime import compat
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return compat.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes)
 
 
 def make_rules(*, multi_pod: bool = False, fsdp: bool = False) -> ShardRules:

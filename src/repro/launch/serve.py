@@ -1,7 +1,12 @@
-"""Serving launcher: continuous-batching engine on a CPU-scale config.
+"""Serving launcher: continuous-batching engine.
 
+CPU-scale (the reduced smoke config, the default):
     PYTHONPATH=src python -m repro.launch.serve --arch tinyllama-1.1b \\
         --requests 6 --max-new 12
+
+Published widths with the depth cut (``--full --layers N``), on a chip:
+    PYTHONPATH=src python -m repro.launch.serve --arch mixtral-8x7b --full \\
+        --layers 2 --requests 4 --max-new 16
 
 ``--tenants N`` switches to the multi-tenant fleet: N independently-seeded
 copies of the arch seated as disjoint D3(1,2) guests on one D3(K,M) host,
@@ -14,12 +19,14 @@ instead, for comparison). Fleet mode needs an MoE arch, e.g.
 from __future__ import annotations
 
 import argparse
+import dataclasses
 import time
 
 import jax
 import numpy as np
 
-from repro.configs import ARCH_IDS, get_smoke_config
+from repro.configs import ARCH_IDS, get_config, get_smoke_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import model as M
 from repro.serve.engine import Engine, Request
 
@@ -35,8 +42,13 @@ def _random_prompts(rng, cfg, n, max_new):
     ]
 
 
-def _serve_single(cfg, args):
-    params = M.init_params(jax.random.key(args.seed), cfg)
+def serve_single(cfg, args):
+    """Serve ``args.requests`` random prompts through one ``Engine``;
+    returns the engine and the completed requests."""
+    # jitted init writes the weights straight into their buffers (eager
+    # init stacks per-layer copies, twice the weights at the peak)
+    params = jax.jit(M.init_params, static_argnums=1)(
+        jax.random.key(args.seed), cfg)
     eng = Engine(cfg, params, batch_slots=args.slots, max_seq=args.max_seq)
 
     rng = np.random.default_rng(args.seed)
@@ -60,7 +72,7 @@ def _serve_single(cfg, args):
           f"{[ (r.rid, len(r.out)) for r in done ]}")
     print(f"engine steps: {eng.steps_run}, wall: {dt:.2f}s, "
           f"tokens: {eng.tokens_out}, tokens/s: {eng.tokens_out / max(dt, 1e-9):.1f}")
-    return eng.steps_run
+    return eng, done
 
 
 def _serve_fleet(cfg, args):
@@ -104,6 +116,10 @@ def _serve_fleet(cfg, args):
 def main(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", choices=ARCH_IDS, default="tinyllama-1.1b")
+    ap.add_argument("--full", action="store_true",
+                    help="published config (default: the reduced smoke config)")
+    ap.add_argument("--layers", type=int, default=0,
+                    help="cut the config's depth to N layers (0 = keep)")
     ap.add_argument("--requests", type=int, default=6)
     ap.add_argument("--slots", type=int, default=4)
     ap.add_argument("--max-new", type=int, default=12)
@@ -117,12 +133,15 @@ def main(argv=None):
                          "sequentially instead of the combined program")
     args = ap.parse_args(argv)
 
-    cfg = get_smoke_config(args.arch)
+    enable_compile_cache()
+    cfg = get_config(args.arch) if args.full else get_smoke_config(args.arch)
+    if args.layers:
+        cfg = dataclasses.replace(cfg, n_layers=args.layers)
     if cfg.embeds_input:
         raise SystemExit("stub-frontend archs serve via decode_step directly")
     if args.tenants:
         return _serve_fleet(cfg, args)
-    return _serve_single(cfg, args)
+    return serve_single(cfg, args)
 
 
 if __name__ == "__main__":

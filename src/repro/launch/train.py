@@ -4,6 +4,10 @@ CPU-scale (runs for real):
     PYTHONPATH=src python -m repro.launch.train --arch tinyllama-1.1b --smoke \\
         --steps 50 --batch 8 --seq 64
 
+Published widths with the depth cut, on a chip:
+    PYTHONPATH=src python -m repro.launch.train --arch tinyllama-1.1b \\
+        --layers 2 --steps 5 --batch 8 --seq 2048
+
 Pod-scale lowering is exercised via launch/dryrun.py; this driver owns the
 real loop: data pipeline -> jitted train step -> checkpoint/restart ->
 straggler accounting. `--restore` resumes from the latest checkpoint
@@ -25,12 +29,14 @@ gradient over the kept contributions.
 from __future__ import annotations
 
 import argparse
+import dataclasses
 import time
 
 import jax
 import numpy as np
 
 from repro.configs import ARCH_IDS, get_config, get_smoke_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.train.optimizer import OptConfig
 from repro.train.train_step import (
     TrainSettings,
@@ -87,10 +93,27 @@ def _run_elastic(args, cfg, opt_cfg, settings) -> float:
     return final
 
 
-def main(argv=None):
+@dataclasses.dataclass
+class TrainRun:
+    """What a plain (non-elastic) ``train_loop`` leaves behind: per-step
+    loss, grad norm and host wall time (the first step includes the
+    compile), the jitted step, and the final state and batch."""
+
+    losses: list
+    grad_norms: list
+    durations: list
+    step_fn: object
+    params: object
+    opt_state: object
+    batch: dict
+
+
+def parse_args(argv=None) -> argparse.Namespace:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", choices=ARCH_IDS, default="tinyllama-1.1b")
     ap.add_argument("--smoke", action="store_true", help="reduced config (CPU)")
+    ap.add_argument("--layers", type=int, default=0,
+                    help="cut the config's depth to N layers (0 = keep)")
     ap.add_argument("--steps", type=int, default=50)
     ap.add_argument("--batch", type=int, default=8)
     ap.add_argument("--seq", type=int, default=64)
@@ -113,19 +136,33 @@ def main(argv=None):
                     help='elastic: explicit kills "step:dev,step:dev,..."')
     ap.add_argument("--inject-random", type=int, default=0,
                     help="elastic: sample N seeded (step, device) kills")
-    args = ap.parse_args(argv)
+    return ap.parse_args(argv)
 
+
+def main(argv=None):
+    args = parse_args(argv)
+    enable_compile_cache()
+    if args.elastic:
+        return _run_elastic(args, *_setup(args))
+    return train_loop(args).losses[-1]
+
+
+def _setup(args):
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
+    if args.layers:
+        cfg = dataclasses.replace(cfg, n_layers=args.layers)
     opt_cfg = OptConfig(lr=args.lr, warmup_steps=10, total_steps=args.steps)
     settings = TrainSettings(
         microbatches=args.microbatches,
-        use_kernel=False,
         remat=True,
         compress_grads=args.compress_grads,
     )
-    if args.elastic:
-        return _run_elastic(args, cfg, opt_cfg, settings)
+    return cfg, opt_cfg, settings
 
+
+def train_loop(args) -> TrainRun:
+    """The plain training loop: data -> jitted step -> checkpoint."""
+    cfg, opt_cfg, settings = _setup(args)
     straggler_drop = args.straggler_drop and args.microbatches > 1
     if straggler_drop:
         # split step: per-microbatch grads are timed on the host so a
@@ -153,6 +190,8 @@ def main(argv=None):
     data = SyntheticLM(data_state)
     policy = StragglerPolicy()
     durations: list[float] = []
+    losses: list[float] = []
+    grad_norms: list[float] = []
 
     for step in range(start_step, args.steps):
         if cfg.embeds_input:
@@ -188,12 +227,14 @@ def main(argv=None):
         loss = float(metrics["loss"])
         dt = time.perf_counter() - t0
         durations.append(dt)
+        losses.append(loss)
+        grad_norms.append(float(metrics["grad_norm"]))
         if not straggler_drop and len(durations) >= 8:
             keep = policy.judge(durations[-8:])
             if not all(keep):
                 print(f"step {step}: straggler flags {keep}")
         print(f"step {step:4d} loss {loss:.4f} "
-              f"gnorm {float(metrics['grad_norm']):.3f} {dt*1e3:.0f} ms")
+              f"gnorm {grad_norms[-1]:.3f} {dt*1e3:.0f} ms")
         if (step + 1) % args.ckpt_every == 0 or step + 1 == args.steps:
             path = ckpt.save(
                 args.ckpt_dir,
@@ -205,7 +246,8 @@ def main(argv=None):
                 },
             )
             print(f"checkpoint -> {path}")
-    return float(metrics["loss"])
+    return TrainRun(losses, grad_norms, durations, step_fn, params, opt_state,
+                    batch)
 
 
 if __name__ == "__main__":

@@ -5,8 +5,7 @@ of the paper's algorithms — into a single ``program.CollectiveProgram``:
 an ordered tuple of primitive stages (``Perm`` / ``Match`` /
 ``ReduceCombine`` / ``LocalContract``), each stamped with the IR
 ``(round_index, step)`` it came from and a ``start_step`` launch offset so
-pipelined schedules survive lowering. ``compat`` papers over jax API drift
-(shard_map moved out of jax.experimental after 0.4.x).
+pipelined schedules survive lowering.
 
 Backend interface contract
 --------------------------
@@ -194,7 +193,6 @@ from repro.runtime import (  # noqa: F401
     autotune,
     backends,
     combine,
-    compat,
     export,
     lowering,
     optimize,

@@ -469,7 +469,6 @@ def _measure_closure(kind: str, site: str, strategy: str, layout, grid,
         from jax.sharding import Mesh, PartitionSpec as P
 
         from repro.dist.collectives import alltoall_program
-        from repro.runtime import compat
         from repro.runtime.backends.jax_ppermute import JaxPpermuteBackend
 
         n = prog.n
@@ -510,7 +509,7 @@ def _measure_closure(kind: str, site: str, strategy: str, layout, grid,
                 be = JaxPpermuteBackend(overlap=(strategy == "overlap"))
                 a2a = lambda v: be.alltoall(v, "df", prog)
             local = lambda s: a2a(comp(a2a(s[0])))[None]
-        f = jax.jit(compat.shard_map(
+        f = jax.jit(jax.shard_map(
             local, mesh=mesh, in_specs=P("df"), out_specs=P("df")))
         xj = jnp.asarray(x)
         return lambda: jax.block_until_ready(f(xj))
@@ -518,20 +517,19 @@ def _measure_closure(kind: str, site: str, strategy: str, layout, grid,
     if strategy == "xla":
         from jax.sharding import Mesh, PartitionSpec as P
 
-        from repro.runtime import compat
 
         mesh = Mesh(np.array(jax.devices()[: prog.n]), ("df",))
         if kind == "alltoall":
-            f = jax.jit(compat.shard_map(
+            f = jax.jit(jax.shard_map(
                 lambda s: jax.lax.all_to_all(
                     s[0], "df", split_axis=0, concat_axis=0)[None],
                 mesh=mesh, in_specs=P("df"), out_specs=P("df")))
         elif kind == "allreduce":
-            f = jax.jit(compat.shard_map(
+            f = jax.jit(jax.shard_map(
                 lambda s: jax.lax.psum(s, "df"),
                 mesh=mesh, in_specs=P("df"), out_specs=P("df")))
         else:  # broadcast root 0: one masked psum
-            f = jax.jit(compat.shard_map(
+            f = jax.jit(jax.shard_map(
                 lambda s: jax.lax.psum(jnp.where(
                     jax.lax.axis_index("df") == 0, s, jnp.zeros_like(s)), "df"),
                 mesh=mesh, in_specs=P("df"), out_specs=P("df")))
@@ -747,12 +745,10 @@ class Autotuner:
         measured: dict[str, float] = {}
         if self.mode == "measure":
             for s in cands:
-                try:
-                    fn = _measure_closure(key.kind, key.site, s, layout, grid,
-                                          key.nbytes, key.dtype,
-                                          key.compute_us)
-                except Exception:
-                    fn = None
+                # None means "cannot run here"; a strategy that fails to
+                # compile or run raises
+                fn = _measure_closure(key.kind, key.site, s, layout, grid,
+                                      key.nbytes, key.dtype, key.compute_us)
                 if fn is not None:
                     measured[s] = _time_us(fn)
         return self._conclude(key, rounds, hops, analytic, measured)
@@ -822,11 +818,8 @@ class Autotuner:
                 measured: dict[str, float] = {}
                 if self.mode == "measure":
                     for s in cands:
-                        try:
-                            fn = _measure_combined_closure(
-                                kind, s, embeddings, key.nbytes, key.dtype)
-                        except Exception:
-                            fn = None
+                        fn = _measure_combined_closure(
+                            kind, s, embeddings, key.nbytes, key.dtype)
                         if fn is not None:
                             measured[s] = _time_us(fn)
                 dec = self._conclude(key, rounds, hops, analytic, measured)

@@ -69,7 +69,6 @@ def _xla_collective(kind: str, n: int, axis_name: str, root: int = 0):
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
 
-    from repro.runtime import compat
     from repro.runtime.backends.jax_ppermute import _axis_mesh
 
     mesh = _axis_mesh(n, axis_name)
@@ -82,7 +81,7 @@ def _xla_collective(kind: str, n: int, axis_name: str, root: int = 0):
         body = lambda s: jax.lax.psum(jnp.where(
             jax.lax.axis_index(axis_name) == root, s, jnp.zeros_like(s)),
             axis_name)
-    return jax.jit(compat.shard_map(
+    return jax.jit(jax.shard_map(
         body, mesh=mesh, in_specs=P(axis_name), out_specs=P(axis_name)))
 
 

@@ -57,7 +57,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.runtime import compat
 from repro.runtime import optimize as _opt
 from repro.runtime.program import (
     CollectiveProgram,
@@ -410,7 +409,7 @@ def _compiled_alltoall_compute(backend: JaxPpermuteBackend,
         fn = None if compute is None else (lambda chunks: compute(chunks, *wl))
         return backend.alltoall_compute(s[0], axis_name, program, fn)[None]
 
-    f = compat.shard_map(
+    f = jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(axis_name),) * (1 + n_weights),
         out_specs=P(axis_name),
@@ -448,11 +447,11 @@ def _compiled_collective(backend: JaxPpermuteBackend, program: CollectiveProgram
             out = backend.broadcast(s, axis_name, program, pipelined=pipelined)
             return out[:, None] if waves else out[None]
 
-        f = compat.shard_map(local, mesh=mesh, in_specs=spec, out_specs=spec)
+        f = jax.shard_map(local, mesh=mesh, in_specs=spec, out_specs=spec)
         return jax.jit(f, donate_argnums=donate)
 
     method = backend.alltoall if kind == "alltoall" else backend.allreduce
-    f = compat.shard_map(
+    f = jax.shard_map(
         lambda s: method(s[0], axis_name, program)[None],
         mesh=mesh, in_specs=P(axis_name), out_specs=P(axis_name),
     )
@@ -471,7 +470,7 @@ def _compiled_matmul(backend: JaxPpermuteBackend, program, axis_name: str,
         replay = _opt.build_jax_matmul(program)
     else:
         m = mesh or _axis_mesh(prog.n, axis_name)
-        replay = compat.shard_map(
+        replay = jax.shard_map(
             lambda bb, aa: backend.matmul(bb[0], aa[0], axis_name, program)[None],
             mesh=m, in_specs=(P(axis_name), P(axis_name)),
             out_specs=P(axis_name),

@@ -9,11 +9,11 @@ pieces into Pallas kernels:
 
   * the per-round permute+accumulate of the allreduce / matmul
     ``ReduceCombine`` stages runs as ONE kernel per program (allreduce) or
-    per fused group (matmul): the stacked (gather, mask) tables drive a
-    ``fori_loop`` inside the kernel, so every round's gather lands in VMEM
-    and the accumulation never leaves the core — the kernel-side analog of
-    a remote-DMA ring step (see ``_rdma_exchange_kernel`` for the actual
-    inter-chip pattern);
+    per fused group (matmul): the stacked (gather, mask) tables ride in as
+    scalar-prefetch operands and drive row reads (``pl.ds``) inside the
+    kernel, gridded over the feature axis so each (n, tile) slab replays
+    every round in VMEM — the kernel-side analog of a remote-DMA ring step
+    (see ``_rdma_exchange_kernel`` for the actual inter-chip pattern);
   * the §2 ``mul_a`` local contraction routes through the existing MXU-tiled
     ``kernels/block_matmul`` Pallas kernel (vmapped over the router-block
     axis) instead of a bare ``@``.
@@ -25,11 +25,11 @@ executes kernel bodies op-by-op): numerically identical to the compiled
 kernel, but *slow* — the smoke tests keep shapes tiny, and the benchmark
 rows labeled ``pallas_fused`` on a CPU host measure the interpreter, not
 the hardware. On a TPU host (``jax.default_backend() == "tpu"``) the same
-entry points compile the kernels for real, and ``run_allreduce`` routes the
-inter-device exchange through ``_rdma_exchange_kernel`` — a
-``make_async_remote_copy`` ring step per round (remote-DMA pattern per the
-Pallas guide) inside the caller's mesh. That path needs physical chips and
-is exercised only on TPU pods, never by the interpret-mode CI.
+entry points compile the kernels for real. ``allreduce_shard`` is the
+per-shard form: one ``_rdma_exchange_kernel`` per round, a
+``make_async_remote_copy`` to the round's partner inside the caller's
+``shard_map``; the TPU interpreter simulates its DMAs and semaphores on
+host devices.
 
 ``run_alltoall`` / ``run_broadcast`` are pure data movement with no
 compute to fuse — they delegate to the optimizer's table replay (one
@@ -48,7 +48,8 @@ import functools
 
 import jax
 import jax.numpy as jnp
-import numpy as np
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.runtime import optimize as _opt
 from repro.runtime.program import CollectiveProgram, check_kind as _check_kind
@@ -59,65 +60,124 @@ def _on_tpu() -> bool:
 
 
 # ---------------------------------------------------------------------------
-# Kernels.
+# Kernels. The (gather, mask) tables ride in as scalar-prefetch int32 arrays
+# (SMEM); the value buffer is gridded over its feature axis, so VMEM holds
+# one (n, tile) slab at a time. Gathers only mix rows (devices), never
+# features, so every feature tile replays the rounds independently.
 # ---------------------------------------------------------------------------
 
-def _reduce_rounds_kernel(g_ref, m_ref, x_ref, o_ref):
-    """Replay R permute+accumulate rounds over the whole (n, F) buffer:
-    round r adds ``where(mask[r, k], val[gather[r, k]], 0)`` rows (stage
-    order) into every device's slot. Tables ride in as int32 tensors; the
-    gather stays in VMEM across all rounds."""
-    rounds, k_rows = g_ref.shape[0], g_ref.shape[1]
-
-    def round_body(r, val):
-        recv = jnp.zeros_like(val)
-        for k in range(k_rows):  # static row count — unrolled, stage order
-            rows = jnp.take(val, g_ref[r, k], axis=0)
-            recv = recv + jnp.where((m_ref[r, k] != 0)[:, None], rows, 0)
-        return val + recv
-
-    o_ref[...] = jax.lax.fori_loop(0, rounds, round_body, x_ref[...])
+_TILE = 8192  # feature lanes per grid step: (n, 8192) f32 is 256 KiB at n=8
 
 
-def _combine_group_kernel(g_ref, m_ref, v_ref, o_ref):
-    """One fused ReduceCombine group: out = Σ_k where(mask[k], val[gather[k]], 0)
-    with rows folded in stage order (bit-exact accumulation)."""
-    val = v_ref[...]
-    acc = jnp.zeros_like(val)
-    for k in range(g_ref.shape[0]):
-        acc = acc + jnp.where((m_ref[k] != 0)[:, None],
-                              jnp.take(val, g_ref[k], axis=0), 0)
-    o_ref[...] = acc
+def _gather_fold(src_ref, g_ref, m_ref, idx, d, acc):
+    """Fold the masked rows ``src[gather[idx + (k, d)]]`` into ``acc`` (a
+    (1, tile) row) in table order k — the reference's stage-order fold; an
+    unmasked row leaves ``acc`` untouched."""
+    for k in range(g_ref.shape[len(idx)]):
+        row = src_ref[pl.ds(g_ref[(*idx, k, d)], 1), :]
+        acc = jnp.where(m_ref[(*idx, k, d)] != 0, acc + row, acc)
+    return acc
+
+
+def _reduce_rounds_kernel(g_ref, m_ref, x_ref, o_ref, recv_ref):
+    """Replay R permute+accumulate rounds on one (n, tile) slab: round r
+    adds Σ_k where(mask[r, k, d], val[gather[r, k, d]]) (stage order) into
+    row d. ``o_ref`` holds the running value; ``recv_ref`` collects one
+    round's arrivals so every read sees the pre-round value."""
+    rounds, _, n = g_ref.shape
+    o_ref[...] = x_ref[...]
+
+    def round_body(r, carry):
+        for d in range(n):  # static row count — unrolled
+            zero = jnp.zeros((1, o_ref.shape[1]), o_ref.dtype)
+            recv_ref[pl.ds(d, 1), :] = _gather_fold(o_ref, g_ref, m_ref, (r,), d, zero)
+        o_ref[...] = o_ref[...] + recv_ref[...]
+        return carry
+
+    jax.lax.fori_loop(0, rounds, round_body, 0)
+
+
+def _combine_group_kernel(g_ref, m_ref, v_ref, a_ref, o_ref):
+    """One fused ReduceCombine group on one slab: row d of the output is
+    acc[d] with the masked rows val[gather[k, d]] folded in, k in stage
+    order (the reference's accumulation order, bit for bit)."""
+    for d in range(g_ref.shape[1]):
+        o_ref[pl.ds(d, 1), :] = _gather_fold(
+            v_ref, g_ref, m_ref, (), d, a_ref[pl.ds(d, 1), :])
+
+
+def _table_call(kernel, tables, operands, scratch: bool, interpret: bool):
+    """``pallas_call`` of a table kernel on (n, F) operands: pad F to whole
+    lane tiles, grid over the tiles, slice the padding off the result."""
+    n, F = operands[0].shape
+    tile = min(_TILE, -(-F // 128) * 128)
+    Fp = -(-F // tile) * tile
+    if Fp != F:
+        operands = [jnp.pad(o, ((0, 0), (0, Fp - F))) for o in operands]
+    spec = pl.BlockSpec((n, tile), lambda j, *_: (0, j))
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=len(tables),
+        grid=(Fp // tile,),
+        in_specs=[spec] * len(operands),
+        out_specs=spec,
+        scratch_shapes=[pltpu.VMEM((n, tile), operands[0].dtype)] if scratch else [],
+    )
+    out = pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((n, Fp), operands[0].dtype),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=(pltpu.PARALLEL,)),
+        interpret=interpret,
+    )(*tables, *operands)
+    return out[:, :F]
+
+
+def reduce_rounds(gather, mask, x, *, interpret: bool = False):
+    """All-reduce table replay of (R, k, n) ``gather``/``mask`` tables on an
+    (n, F) value through ``_reduce_rounds_kernel``."""
+    return _table_call(_reduce_rounds_kernel,
+                       (gather.astype(jnp.int32), mask.astype(jnp.int32)),
+                       [x], True, interpret)
+
+
+def combine_group(gather, mask, val, acc, *, interpret: bool = False):
+    """One ReduceCombine group of (k, n) tables folded into (n, F) ``acc``
+    through ``_combine_group_kernel``."""
+    return _table_call(_combine_group_kernel,
+                       (gather.astype(jnp.int32), mask.astype(jnp.int32)),
+                       [val, acc], False, interpret)
 
 
 def _rdma_exchange_kernel(partner_ref, x_ref, o_ref, send_sem, recv_sem):
-    """TPU-only ring step: ship this device's buffer to ``partner`` over the
-    interconnect (remote-DMA pattern per the Pallas guide). Runs inside
-    shard_map; ``partner_ref`` is scalar-prefetched per device."""
-    from jax.experimental.pallas import tpu as pltpu
-
+    """Ring step: ship this device's buffer to ``partner`` over the
+    interconnect. Partners are involutions, so the partner sends to us at
+    the same time. The barrier handshake first makes sure the partner is
+    inside this kernel (its output buffer live) before the DMA lands."""
+    partner = partner_ref[0]
+    barrier = pltpu.get_barrier_semaphore()
+    pltpu.semaphore_signal(barrier, 1, device_id=partner,
+                           device_id_type=pltpu.DeviceIdType.LOGICAL)
+    pltpu.semaphore_wait(barrier, 1)
     rdma = pltpu.make_async_remote_copy(
         src_ref=x_ref,
         dst_ref=o_ref,
         send_sem=send_sem,
         recv_sem=recv_sem,
-        device_id=(partner_ref[0],),
+        device_id=partner,
         device_id_type=pltpu.DeviceIdType.LOGICAL,
     )
     rdma.start()
     rdma.wait()
 
 
-def _tpu_ring_exchange(x, partner, axis_name):  # pragma: no cover - TPU only
-    """Per-shard remote-DMA permute: send local ``x`` to ``partner``."""
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
+def _ring_exchange(x, partner, interpret):
+    """Per-shard remote-DMA permute: send local ``x`` to ``partner`` (its
+    index on the 1-D mesh axis, which is its logical device id there)."""
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(1,),
-        in_specs=[pl.BlockSpec(memory_space=pltpu.VMEM)],
-        out_specs=pl.BlockSpec(memory_space=pltpu.VMEM),
+        in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=pl.BlockSpec(memory_space=pl.ANY),
         scratch_shapes=[pltpu.SemaphoreType.DMA(()), pltpu.SemaphoreType.DMA(())],
     )
     return pl.pallas_call(
@@ -126,25 +186,18 @@ def _tpu_ring_exchange(x, partner, axis_name):  # pragma: no cover - TPU only
         out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype),
         compiler_params=pltpu.CompilerParams(has_side_effects=True,
                                              collective_id=0),
+        interpret=pltpu.InterpretParams() if interpret else False,
     )(partner.reshape(1), x)
 
 
 @functools.lru_cache(maxsize=None)
 def _allreduce_executor(opt: _opt.OptimizedProgram, interpret: bool):
-    from jax.experimental import pallas as pl
-
     gat, msk = _opt.stacked_combine_tables(opt)
-    msk = msk.astype(np.int32)  # kernel tables: bool -> int32 lanes
     n = opt.n
 
     @jax.jit
     def run(x):
-        flat = x.reshape(n, -1)
-        out = pl.pallas_call(
-            _reduce_rounds_kernel,
-            out_shape=jax.ShapeDtypeStruct(flat.shape, flat.dtype),
-            interpret=interpret,
-        )(gat, msk, flat)
+        out = reduce_rounds(gat, msk, x.reshape(n, -1), interpret=interpret)
         return out.reshape(x.shape)
 
     return run
@@ -152,20 +205,14 @@ def _allreduce_executor(opt: _opt.OptimizedProgram, interpret: bool):
 
 @functools.lru_cache(maxsize=None)
 def _matmul_executor(opt: _opt.OptimizedProgram, interpret: bool):
-    from jax.experimental import pallas as pl
-
     from repro.kernels.block_matmul.ops import batched_matmul
 
     n = opt.n
 
     def combine_fn(acc, val, gather, mask):
-        flat = val.reshape(n, -1)
-        out = pl.pallas_call(
-            _combine_group_kernel,
-            out_shape=jax.ShapeDtypeStruct(flat.shape, flat.dtype),
-            interpret=interpret,
-        )(gather.astype(jnp.int32), mask.astype(jnp.int32), flat)
-        return acc + out.reshape(val.shape)
+        out = combine_group(gather, mask, val.reshape(n, -1),
+                            acc.reshape(n, -1), interpret=interpret)
+        return out.reshape(val.shape)
 
     def mul_fn(val, a):
         return batched_matmul(val, a, interpret=interpret)
@@ -223,19 +270,20 @@ class PallasFusedBackend:
         return _opt.jax_gather_blocks(_opt.jax_gather_guest(replay(b, a), prog),
                                       prog.grid)
 
-    # ------------------------------------------------- per-shard (TPU ring)
-    def allreduce_shard(self, x, axis_name: str,
-                        program: CollectiveProgram):  # pragma: no cover - TPU
+    # ------------------------------------------------------ per-shard ring
+    def allreduce_shard(self, x, axis_name: str, program: CollectiveProgram):
         """Per-shard §4 all-reduce with the remote-DMA ring kernel: one
-        RDMA exchange + local accumulate per round. TPU meshes only — the
-        interpreter cannot simulate cross-chip DMA, which is why CPU CI
-        exercises ``run_allreduce``'s table kernel instead."""
+        RDMA exchange + local accumulate per round, inside the caller's
+        ``shard_map`` over a 1-D axis of ``program.n`` devices. Interpret
+        mode simulates the DMAs and semaphores across the host's devices;
+        compiled mode needs TPU chips."""
         prog = _opt.as_program(program)
         _check_kind(prog, "allreduce")
-        if not _on_tpu():
+        interpret = self._interp()
+        if not interpret and not _on_tpu():
             raise RuntimeError(
-                "allreduce_shard needs TPU remote DMA; use run_allreduce "
-                "(interpret-mode table kernel) on CPU hosts"
+                "compiled allreduce_shard needs TPU remote DMA; use "
+                "interpret mode or run_allreduce on CPU hosts"
             )
         idx = jax.lax.axis_index(axis_name)
         for st in prog.comm_stages:
@@ -244,7 +292,6 @@ class PallasFusedBackend:
                     "RDMA ring path handles native (full-involution) "
                     "programs; replay emulated programs via run_allreduce"
                 )
-            partner = jnp.asarray(st.inverse_np)[idx]
-            recv = _tpu_ring_exchange(x, partner.astype(jnp.int32), axis_name)
-            x = x + recv
+            partner = jnp.asarray(st.inverse_np, jnp.int32)[idx]
+            x = x + _ring_exchange(x, partner, interpret)
         return x

@@ -10,6 +10,7 @@ exercised via the dry-run; this engine runs for real on CPU-scale configs.
 from __future__ import annotations
 
 import dataclasses
+import time
 
 import jax
 import jax.numpy as jnp
@@ -42,6 +43,9 @@ class Engine:
         )
         self.steps_run = 0
         self.tokens_out = 0  # decoded (committed) tokens, for tokens/s
+        # host seconds per batched forward, logits on the host included;
+        # the first one includes the decode step's compile
+        self.forward_s: list[float] = []
 
     @property
     def free_slots(self):
@@ -90,7 +94,10 @@ class Engine:
         return np.asarray(logits, np.float32)
 
     def _advance(self, decode_slots):
-        return self._commit(self._forward(), decode_slots)
+        t0 = time.perf_counter()
+        logits = self._forward()
+        self.forward_s.append(time.perf_counter() - t0)
+        return self._commit(logits, decode_slots)
 
     def _commit(self, logits, decode_slots):
         """Book one forward's results: bump positions, argmax-append for the

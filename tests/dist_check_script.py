@@ -14,7 +14,7 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.dist.mesh import dragonfly_layout
 from repro.dist import collectives as coll
-from repro.runtime.compat import shard_map
+from jax import shard_map
 
 
 def get_mesh(n):

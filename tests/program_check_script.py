@@ -37,7 +37,7 @@ from repro.core import matmul as mm
 from repro.core.emulation import embed
 from repro.core.topology import D3
 from repro.dist.mesh import DeviceLayout
-from repro.runtime import compat, lowering
+from repro.runtime import lowering
 from repro.runtime import optimize as ropt
 from repro.runtime.backends.jax_ppermute import JaxPpermuteBackend
 from repro.runtime.backends.reference import NumpyReferenceBackend
@@ -108,7 +108,7 @@ def check_matmul_hlo_no_gather():
     mesh = mesh_of(prog.n)
     b = jnp.zeros((prog.n, 2, 2), jnp.float32)
     f = jax.jit(
-        compat.shard_map(
+        jax.shard_map(
             lambda bb, aa: coll.dragonfly_matmul(bb[0], aa[0], "df", (2, 2))[None],
             mesh=mesh, in_specs=(P("df"), P("df")), out_specs=P("df"),
         )

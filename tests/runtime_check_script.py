@@ -26,7 +26,7 @@ from repro.core import matmul as mm
 from repro.dist.mesh import dragonfly_layout
 from repro.runtime import lowering
 from repro.runtime.backends.jax_ppermute import JaxPpermuteBackend
-from repro.runtime.compat import shard_map
+from jax import shard_map
 
 N = 8
 BACKEND = JaxPpermuteBackend()

@@ -99,3 +99,24 @@ def test_flash_matches_big_kv_tiling():
     a = flash_attention(q, k, v, bq=64, bk=64, interpret=True)
     b = flash_attention(q, k, v, bq=64, bk=256, interpret=True)
     np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("causal,window", [(True, None), (True, 64), (False, None)])
+def test_flash_grad_vs_ref(causal, window):
+    """The kernel's custom VJP (XLA recompute backward) gives the
+    gradients of the materialized oracle: f32, atol/rtol 1e-4."""
+    rng = np.random.default_rng(7)
+    BH, S, D = 2, 256, 64
+    q, k, v, ct = (jnp.asarray(rng.standard_normal((BH, S, D)), jnp.float32)
+                   for _ in range(4))
+
+    def loss(fn):
+        return lambda q, k, v: jnp.sum(fn(q, k, v) * ct)
+
+    got = jax.grad(loss(lambda q, k, v: flash_attention(
+        q, k, v, causal=causal, window=window, bq=64, bk=64, interpret=True)),
+        argnums=(0, 1, 2))(q, k, v)
+    want = jax.grad(loss(lambda q, k, v: attention_ref(
+        q, k, v, causal=causal, window=window)), argnums=(0, 1, 2))(q, k, v)
+    for g, w in zip(got, want):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w), rtol=1e-4, atol=1e-4)
