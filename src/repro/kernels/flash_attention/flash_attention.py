@@ -128,8 +128,9 @@ def _flash_bwd(causal, window, scale, bq, bk, interpret, res, g):
             q[None], k[None], v[None], causal=causal, window=window, scale=scale,
         )[0]
 
-    _, vjp = jax.vjp(xla, *res)
-    return vjp(g)
+    with jax.named_scope("attn.flash_bwd"):
+        _, vjp = jax.vjp(xla, *res)
+        return vjp(g)
 
 
 _flash.defvjp(_flash_fwd, _flash_bwd)

@@ -55,12 +55,14 @@ def _project_qkv(params, x, cfg, positions, mrope_positions=None):
 
 
 def gqa_train(params, x, cfg, positions, mrope_positions=None, use_kernel=True):
-    q, k, v = _project_qkv(params, x, cfg, positions, mrope_positions)
+    with jax.named_scope("attn.qkv"):
+        q, k, v = _project_qkv(params, x, cfg, positions, mrope_positions)
     o = gqa_attention(
         q, k, v, causal=True, window=cfg.sliding_window, use_kernel=use_kernel
     )
     B, S = x.shape[:2]
-    return o.reshape(B, S, -1) @ params["wo"]
+    with jax.named_scope("attn.out"):
+        return o.reshape(B, S, -1) @ params["wo"]
 
 
 def gqa_decode(params, x, cache, cfg, position, mrope_positions=None):
@@ -69,28 +71,34 @@ def gqa_decode(params, x, cache, cfg, position, mrope_positions=None):
     B = x.shape[0]
     hd = cfg.head_dim
     pos_b = jnp.broadcast_to(jnp.asarray(position, jnp.int32), (B,))
-    q, k, v = _project_qkv(
-        params, x, cfg,
-        positions=pos_b[:, None],
-        mrope_positions=mrope_positions,
-    )
-    bidx = jnp.arange(B)
-    ck = cache["k"].at[bidx, :, pos_b].set(k[:, 0].astype(cache["k"].dtype))
-    cv = cache["v"].at[bidx, :, pos_b].set(v[:, 0].astype(cache["v"].dtype))
+    positions = pos_b[:, None]
+    with jax.named_scope("attn.qkv"):
+        q, k, v = _project_qkv(
+            params, x, cfg,
+            positions=positions,
+            mrope_positions=mrope_positions,
+        )
+    with jax.named_scope("attn.kv_update"):
+        bidx = jnp.arange(B)
+        ck = cache["k"].at[bidx, :, pos_b].set(k[:, 0].astype(cache["k"].dtype))
+        cv = cache["v"].at[bidx, :, pos_b].set(v[:, 0].astype(cache["v"].dtype))
     # masked single-query attention over the cache (memory-bound: jnp path)
-    G = cfg.n_heads // cfg.n_kv_heads
-    qh = q.reshape(B, 1, cfg.n_kv_heads, G, hd)
-    s = jnp.einsum("bqhgd,bhkd->bhgk", qh.astype(jnp.float32), ck.astype(jnp.float32))
-    s = s * (hd ** -0.5)
-    kpos = jnp.arange(ck.shape[2])
-    valid = kpos[None, :] <= pos_b[:, None]  # (B, S)
-    if cfg.sliding_window is not None:
-        valid &= kpos[None, :] > pos_b[:, None] - cfg.sliding_window
-    s = jnp.where(valid[:, None, None, :], s, -1e30)
-    p = jax.nn.softmax(s, axis=-1)
-    o = jnp.einsum("bhgk,bhkd->bhgd", p, cv.astype(jnp.float32))
-    o = o.reshape(B, 1, cfg.n_heads * hd).astype(x.dtype)
-    return o @ params["wo"], {"k": ck, "v": cv}
+    with jax.named_scope("attn.decode"):
+        G = cfg.n_heads // cfg.n_kv_heads
+        qh = q.reshape(B, 1, cfg.n_kv_heads, G, hd)
+        s = jnp.einsum("bqhgd,bhkd->bhgk", qh.astype(jnp.float32), ck.astype(jnp.float32))
+        s = s * (hd ** -0.5)
+        kpos = jnp.arange(ck.shape[2])
+        valid = kpos[None, :] <= pos_b[:, None]  # (B, S)
+        if cfg.sliding_window is not None:
+            valid &= kpos[None, :] > pos_b[:, None] - cfg.sliding_window
+        s = jnp.where(valid[:, None, None, :], s, -1e30)
+        p = jax.nn.softmax(s, axis=-1)
+        o = jnp.einsum("bhgk,bhkd->bhgd", p, cv.astype(jnp.float32))
+        o = o.reshape(B, 1, cfg.n_heads * hd).astype(x.dtype)
+    with jax.named_scope("attn.out"):
+        o = o @ params["wo"]
+    return o, {"k": ck, "v": cv}
 
 
 def gqa_cache_init(cfg, batch, max_seq, dtype):

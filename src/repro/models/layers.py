@@ -116,6 +116,7 @@ def mlp_init(key, d_model, d_ff, dtype, gated=True):
     return p
 
 
+@jax.named_scope("mlp.ffn")
 def mlp_apply(params, x, act=jax.nn.silu):
     h = x @ params["w_in"]
     if "w_gate" in params:
@@ -137,6 +138,7 @@ def embed_init(key, vocab, d_model, dtype):
     return {"table": truncated_normal(key, (vocab, d_model), dtype, 1.0)}
 
 
+@jax.named_scope("embed.lookup")
 def embed_apply(params, tokens):
     return jnp.take(params["table"], tokens, axis=0)
 

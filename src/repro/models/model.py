@@ -110,6 +110,7 @@ def _norm_f(cfg):
     return L.rmsnorm if cfg.norm == "rmsnorm" else L.layernorm
 
 
+@jax.named_scope("unembed.logits")
 def _unembed(params, h, cfg):
     if cfg.tie_embeddings:
         return L.unembed_apply(params["embed"], h)
@@ -130,6 +131,7 @@ def mtp_logits(params, h, batch, cfg, use_kernel=True):
     return _unembed(params, z, cfg)
 
 
+@jax.named_scope("loss.xent")
 def softmax_xent(logits, labels, valid=None):
     lf = logits.astype(jnp.float32)
     logz = jax.nn.logsumexp(lf, axis=-1)

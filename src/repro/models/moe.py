@@ -123,35 +123,39 @@ def moe_apply_sparse(params, x, cfg, capacity_factor: float | None = None):
     t_ax = act[0].tensor_axis if act else None
     b_ax = act[0].batch_axes if act else None
     xt = x.reshape(T, d)
-    logits = xt @ params["router"]
-    w, idx = router_topk(logits, m.top_k, m.norm_topk_probs)  # (T,k)
-    flat_e = idx.reshape(-1)  # (T*k,)
-    # position of each (t, k) within its expert's buffer
-    onehot = jax.nn.one_hot(flat_e, E, dtype=jnp.int32)  # (T*k, E)
-    pos_in_e = (jnp.cumsum(onehot, axis=0) - 1) * onehot  # (T*k, E)
-    slot = pos_in_e.sum(-1)  # (T*k,)
-    keep = slot < C
-    buf = jnp.zeros((E, C, d), xt.dtype)
-    src_tok = jnp.repeat(jnp.arange(T), m.top_k)
-    buf = buf.at[flat_e, jnp.clip(slot, 0, C - 1)].add(
-        jnp.where(keep[:, None], xt[src_tok], 0)
-    )
-    if act:  # the §3 all-to-all boundary: tokens -> expert-major buffers
-        buf = SH.constrain(buf, t_ax if ep else None, b_ax, None)
-    h = jax.nn.silu(jnp.einsum("ecd,edf->ecf", buf, params["w_gate"])) * jnp.einsum(
-        "ecd,edf->ecf", buf, params["w_in"]
-    )
-    if act:
-        h = SH.constrain(h, t_ax if ep else None, b_ax, None if ep else t_ax)
-    y_buf = jnp.einsum("ecf,efd->ecd", h, params["w_out"])  # (E, C, d)
-    if act:  # combine all-to-all boundary
-        y_buf = SH.constrain(y_buf, t_ax if ep else None, b_ax, None)
-    y = jnp.zeros((T, d), jnp.float32)
-    gathered = y_buf[flat_e, jnp.clip(slot, 0, C - 1)]
-    y = y.at[src_tok].add(
-        jnp.where(keep[:, None], gathered.astype(jnp.float32) * w.reshape(-1)[:, None], 0)
-    )
-    y = y.astype(x.dtype)
+    with jax.named_scope("moe.router"):
+        logits = xt @ params["router"]
+        w, idx = router_topk(logits, m.top_k, m.norm_topk_probs)  # (T,k)
+    with jax.named_scope("moe.dispatch"):
+        flat_e = idx.reshape(-1)  # (T*k,)
+        # position of each (t, k) within its expert's buffer
+        onehot = jax.nn.one_hot(flat_e, E, dtype=jnp.int32)  # (T*k, E)
+        pos_in_e = (jnp.cumsum(onehot, axis=0) - 1) * onehot  # (T*k, E)
+        slot = pos_in_e.sum(-1)  # (T*k,)
+        keep = slot < C
+        buf = jnp.zeros((E, C, d), xt.dtype)
+        src_tok = jnp.repeat(jnp.arange(T), m.top_k)
+        buf = buf.at[flat_e, jnp.clip(slot, 0, C - 1)].add(
+            jnp.where(keep[:, None], xt[src_tok], 0)
+        )
+        if act:  # the §3 all-to-all boundary: tokens -> expert-major buffers
+            buf = SH.constrain(buf, t_ax if ep else None, b_ax, None)
+    with jax.named_scope("moe.expert_ffn"):
+        h = jax.nn.silu(jnp.einsum("ecd,edf->ecf", buf, params["w_gate"])) * jnp.einsum(
+            "ecd,edf->ecf", buf, params["w_in"]
+        )
+        if act:
+            h = SH.constrain(h, t_ax if ep else None, b_ax, None if ep else t_ax)
+        y_buf = jnp.einsum("ecf,efd->ecd", h, params["w_out"])  # (E, C, d)
+    with jax.named_scope("moe.combine"):
+        if act:  # combine all-to-all boundary
+            y_buf = SH.constrain(y_buf, t_ax if ep else None, b_ax, None)
+        y = jnp.zeros((T, d), jnp.float32)
+        gathered = y_buf[flat_e, jnp.clip(slot, 0, C - 1)]
+        y = y.at[src_tok].add(
+            jnp.where(keep[:, None], gathered.astype(jnp.float32) * w.reshape(-1)[:, None], 0)
+        )
+        y = y.astype(x.dtype)
     if "shared" in params:
         y = y + L.mlp_apply(params["shared"], xt)
     aux = load_balance_loss(logits, idx, E, m.top_k)

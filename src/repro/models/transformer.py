@@ -244,7 +244,9 @@ def stack_decode(stack_params, x, caches, cfg, position, mrope_positions=None,
         new_caches = jax.tree.map(lambda *xs: jnp.stack(xs), *outs)
         return x, new_caches
 
-    x, new_caches = jax.lax.scan(group_fn, x, (stack_params, caches))
+    # the loop's own slicing of the stacked caches and their write-back
+    with jax.named_scope("decode.layers"):
+        x, new_caches = jax.lax.scan(group_fn, x, (stack_params, caches))
     return x, new_caches
 
 

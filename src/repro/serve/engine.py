@@ -5,6 +5,16 @@ decode step (scalar-or-(B,) position support in the attention caches).
 The engine drives the pure ``decode_step``; prefill feeds prompt tokens
 through the same cached path (functionally exact). Pod-scale shapes are
 exercised via the dry-run; this engine runs for real on CPU-scale configs.
+
+Observability: the engine marks its seams with ``jax.profiler``
+annotations, which land in a profiler trace beside the device's
+operations when one is being recorded and cost about a microsecond each
+when none is: ``engine.admit(rid, prompt_tokens)``, then per batched
+forward ``engine.step(kind, slots[, rid])`` holding ``engine.inputs``
+(host-to-device copies), ``engine.dispatch`` (the jitted call until it
+returns), ``engine.fetch`` (waiting for the logits and copying them to
+the host) and ``engine.commit``. ``Engine.stats`` counts the work;
+``Request`` carries its own timestamps.
 """
 
 from __future__ import annotations
@@ -15,6 +25,7 @@ import time
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.models import model as M
 
@@ -26,6 +37,25 @@ class Request:
     max_new_tokens: int
     out: list = dataclasses.field(default_factory=list)
     done: bool = False
+    # ``time.perf_counter()`` seconds. ``arrival`` is stamped by whoever
+    # queues the request; the engine stamps when ``admit`` seats it and
+    # when its first token is committed. Queue wait: admitted_at - arrival.
+    arrival: float | None = None
+    admitted_at: float | None = None
+    first_token_at: float | None = None
+
+
+@dataclasses.dataclass
+class EngineStats:
+    """What the engine has done since it started or since ``reset()``."""
+    steps: int = 0             # batched forwards committed
+    prefill_steps: int = 0     # of them, run inside ``admit`` (a prompt token)
+    tokens_processed: int = 0  # occupied slots, summed over steps
+    decode_tokens: int = 0     # tokens committed to requests
+    context: int = 0           # positions in use (position + 1), summed
+
+    def reset(self):
+        self.__init__()
 
 
 class Engine:
@@ -41,11 +71,17 @@ class Engine:
         self._step = jax.jit(
             lambda p, c, b, pos: M.decode_step(p, c, b, pos, self.cfg)
         )
-        self.steps_run = 0
-        self.tokens_out = 0  # decoded (committed) tokens, for tokens/s
-        # host seconds per batched forward, logits on the host included;
-        # the first one includes the decode step's compile
-        self.forward_s: list[float] = []
+        self.stats = EngineStats()
+        self._prefill_rid = None  # the request ``admit`` is prefilling
+
+    @property
+    def steps_run(self) -> int:
+        return self.stats.steps
+
+    @property
+    def tokens_out(self) -> int:
+        """Decoded (committed) tokens, for tokens/s."""
+        return self.stats.decode_tokens
 
     @property
     def free_slots(self):
@@ -71,14 +107,22 @@ class Engine:
         if not free:
             return False
         slot = free[0]
-        self.slot_req[slot] = req
-        self.positions[slot] = 0
-        # prefill: feed prompt tokens through the cached decode path; the
-        # other slots advance with their own pending tokens (no stalls).
-        for tok in req.prompt[:-1]:
-            self.pending_tok[slot] = int(tok)
-            self._advance(decode_slots=[s for s in self.slot_req if s != slot])
-        self.pending_tok[slot] = int(req.prompt[-1])
+        with TraceAnnotation("engine.admit", rid=req.rid,
+                             prompt_tokens=len(req.prompt)):
+            req.admitted_at = time.perf_counter()
+            self.slot_req[slot] = req
+            self.positions[slot] = 0
+            # prefill: feed prompt tokens through the cached decode path;
+            # the other slots advance with their own pending tokens (no
+            # stalls).
+            self._prefill_rid = req.rid
+            try:
+                for tok in req.prompt[:-1]:
+                    self.pending_tok[slot] = int(tok)
+                    self._advance(decode_slots=[s for s in self.slot_req if s != slot])
+            finally:
+                self._prefill_rid = None
+            self.pending_tok[slot] = int(req.prompt[-1])
         return True
 
     # -------------------------------------------------------------- step
@@ -87,32 +131,53 @@ class Engine:
         override — ``serve.fleet.FleetEngine`` runs the staged decode here
         so MoE boundaries can be serviced by a combined host program).
         Returns host logits (slots, vocab) and updates ``self.cache``."""
-        batch = {"token": jnp.asarray(self.pending_tok)}
-        logits, self.cache = self._step(
-            self.params, self.cache, batch, jnp.asarray(self.positions)
-        )
-        return np.asarray(logits, np.float32)
+        logits = self._dispatch()
+        with TraceAnnotation("engine.fetch"):
+            return np.asarray(logits, np.float32)
+
+    def _dispatch(self) -> jax.Array:
+        """Copy the step's inputs to the device and launch the decode step;
+        returns its logits, still on the device."""
+        with TraceAnnotation("engine.inputs"):
+            batch = {"token": jnp.asarray(self.pending_tok)}
+            positions = jnp.asarray(self.positions)
+        with TraceAnnotation("engine.dispatch"):
+            logits, self.cache = self._step(self.params, self.cache, batch, positions)
+        return logits
 
     def _advance(self, decode_slots):
-        t0 = time.perf_counter()
-        logits = self._forward()
-        self.forward_s.append(time.perf_counter() - t0)
-        return self._commit(logits, decode_slots)
+        if self._prefill_rid is None:
+            tags = {"kind": "decode"}
+        else:
+            tags = {"kind": "prefill", "rid": self._prefill_rid}
+        with TraceAnnotation("engine.step", slots=len(self.slot_req), **tags):
+            logits = self._forward()
+            return self._commit(logits, decode_slots)
 
     def _commit(self, logits, decode_slots):
-        """Book one forward's results: bump positions, argmax-append for the
-        decoding slots, retire finished requests and free their slots."""
-        self.steps_run += 1
-        self.positions[list(self.slot_req)] += 1
-        for slot in decode_slots:
-            req = self.slot_req[slot]
-            nxt = int(np.argmax(logits[slot]))
-            req.out.append(nxt)
-            self.tokens_out += 1
-            self.pending_tok[slot] = nxt
-            if len(req.out) >= req.max_new_tokens or self.positions[slot] >= self.max_seq - 1:
-                req.done = True
-                del self.slot_req[slot]
+        """Book one forward's results: count it, bump positions,
+        argmax-append for the decoding slots, retire finished requests and
+        free their slots."""
+        with TraceAnnotation("engine.commit"):
+            active = list(self.slot_req)
+            st = self.stats
+            st.steps += 1
+            st.prefill_steps += self._prefill_rid is not None
+            st.tokens_processed += len(active)
+            st.context += int(self.positions[active].sum()) + len(active)
+            st.decode_tokens += len(decode_slots)
+            self.positions[active] += 1
+            now = time.perf_counter()
+            for slot in decode_slots:
+                req = self.slot_req[slot]
+                nxt = int(np.argmax(logits[slot]))
+                if not req.out:
+                    req.first_token_at = now
+                req.out.append(nxt)
+                self.pending_tok[slot] = nxt
+                if len(req.out) >= req.max_new_tokens or self.positions[slot] >= self.max_seq - 1:
+                    req.done = True
+                    del self.slot_req[slot]
         return logits
 
     def step(self):

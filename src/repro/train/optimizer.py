@@ -90,6 +90,7 @@ def global_norm(tree):
     )
 
 
+@jax.named_scope("optimizer.adamw")
 def apply_updates(params, grads, state, cfg: OptConfig):
     """One AdamW/AdaFactor step. Returns (new_params, new_state, metrics)."""
     step = state["step"] + 1

@@ -157,3 +157,91 @@ def test_max_seq_truncation(setup):
     want = greedy_reference(cfg, params, req.prompt, len(req.out))
     assert req.out == want
     assert eng.tokens_out == len(req.out)
+
+
+# ------------------------------------------------------------ observability
+def _tiny_moe():
+    """The benchmark's tiny MoE decoder (``bench/tests/tiny.py``)."""
+    import pathlib
+    import sys
+
+    root = str(pathlib.Path(__file__).resolve().parents[1])
+    if root not in sys.path:
+        sys.path.insert(0, root)
+    from bench.families import decoder
+    from bench.tests import tiny
+
+    cfg = tiny.CONFIGS["tiny-moe"]
+    return decoder.program_config(cfg), decoder.make_params(cfg, 0)
+
+
+def _drive(eng):
+    """Admissions that overlap decoding, then decode to the end."""
+    reqs = [Request(rid=i, prompt=np.arange(2 + i, dtype=np.int32) + 1,
+                    max_new_tokens=3 + i) for i in range(3)]
+    eng.admit(reqs[0])
+    eng.step()
+    eng.admit(reqs[1])
+    eng.step()
+    eng.admit(reqs[2])
+    eng.run_to_completion()
+    return reqs
+
+
+def test_engine_stats_equal_the_harness_counts():
+    """``Engine.stats`` counts what the benchmark's ``TimedEngine`` counts
+    around the engine's seams, over the same run."""
+    from bench.loops.serve_open import _engine_class
+
+    cfg, params = _tiny_moe()
+    eng = _engine_class()(cfg, params, 2, 32)
+    reqs = _drive(eng)
+    assert all(r.done for r in reqs)
+    st = eng.stats
+    for k in ("steps", "prefill_steps", "tokens_processed", "decode_tokens", "context"):
+        assert getattr(st, k) == eng.n[k], k
+    assert st.steps == eng.steps_run and st.decode_tokens == eng.tokens_out
+    assert st.decode_tokens == sum(len(r.out) for r in reqs)
+    assert st.prefill_steps == sum(len(r.prompt) - 1 for r in reqs)
+    st.reset()
+    assert eng.steps_run == 0 and eng.tokens_out == 0
+
+
+def test_request_timestamps_are_set_and_ordered(setup):
+    cfg, params = setup
+    eng = Engine(cfg, params, batch_slots=2, max_seq=64)
+    reqs = _drive(eng)
+    for r in reqs:
+        assert r.arrival is None  # the caller's to stamp
+        assert r.admitted_at is not None and r.first_token_at is not None
+        assert r.admitted_at < r.first_token_at
+    assert reqs[0].admitted_at < reqs[1].admitted_at < reqs[2].admitted_at
+
+
+def test_engine_spans_in_a_cpu_trace(setup, tmp_path):
+    """One admission and one step leave each ``engine.*`` span in a
+    profiler trace, the admission and the prefill steps with their rid."""
+    import glob
+
+    from jax.profiler import ProfileData
+
+    cfg, params = setup
+    eng = Engine(cfg, params, batch_slots=2, max_seq=64)
+    eng.admit(Request(rid=0, prompt=np.asarray([1, 2], np.int32), max_new_tokens=4))
+    eng.step()  # compile outside the trace
+    jax.profiler.start_trace(str(tmp_path))
+    eng.admit(Request(rid=7, prompt=np.asarray([3, 4, 5], np.int32), max_new_tokens=2))
+    eng.step()
+    jax.profiler.stop_trace()
+    pd = ProfileData.from_file(glob.glob(f"{tmp_path}/**/*.xplane.pb", recursive=True)[-1])
+    spans = [(e.name, dict(e.stats)) for p in pd.planes if p.name.startswith("/host:")
+             for line in p.lines for e in line.events if e.name.startswith("engine.")]
+    names = [n for n, _ in spans]
+    for n in ("engine.inputs", "engine.dispatch", "engine.fetch", "engine.commit"):
+        assert names.count(n) == 3, n  # two prefill steps and one decode step
+    assert [s for n, s in spans if n == "engine.admit"] == [{"rid": 7, "prompt_tokens": 3}]
+    steps = [s for n, s in spans if n == "engine.step"]
+    assert sorted(steps, key=lambda s: s["kind"]) == [
+        {"kind": "decode", "slots": 2},
+        {"kind": "prefill", "rid": 7, "slots": 2},
+        {"kind": "prefill", "rid": 7, "slots": 2}]
