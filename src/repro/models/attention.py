@@ -12,7 +12,8 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from repro.models import layers as L
-from repro.kernels.flash_attention.ops import gqa_attention
+from repro.kernels.flash_attention.decode import decode_attention
+from repro.kernels.flash_attention.ops import default_impl, gqa_attention
 from repro.kernels.flash_attention.ref import attention_ref
 
 
@@ -65,9 +66,20 @@ def gqa_train(params, x, cfg, positions, mrope_positions=None, use_kernel=True):
         return o.reshape(B, S, -1) @ params["wo"]
 
 
-def gqa_decode(params, x, cache, cfg, position, mrope_positions=None):
-    """x: (B, 1, d); cache: {'k','v'}: (B, kv_heads, max_seq, hd); position
-    scalar int OR (B,) array (per-slot positions — continuous batching)."""
+def write_rows(cache, layer, pos_b, rows):
+    """Write the new token's rows into every layer's cache, in place:
+    ``cache`` (L, B, max_seq, ...), ``rows`` (B, ...) at layer ``layer``
+    and each slot's position ``pos_b`` (B,). The indexed axes lead, so
+    XLA's scatter writes into the buffer as it lies."""
+    return cache.at[layer, jnp.arange(rows.shape[0]), pos_b].set(rows.astype(cache.dtype))
+
+
+def gqa_decode(params, x, cache, layer, cfg, position, mrope_positions=None):
+    """x: (B, 1, d); cache: {'k','v'}: (L, B, max_seq, kv_heads, hd), every
+    layer's; ``layer`` the one this call writes and reads; position scalar
+    int OR (B,) array (per-slot positions — continuous batching). Writes
+    the new token's K/V at ``position``, then attends over the layer's
+    positions up to it. Returns (out, cache)."""
     B = x.shape[0]
     hd = cfg.head_dim
     pos_b = jnp.broadcast_to(jnp.asarray(position, jnp.int32), (B,))
@@ -79,33 +91,43 @@ def gqa_decode(params, x, cache, cfg, position, mrope_positions=None):
             mrope_positions=mrope_positions,
         )
     with jax.named_scope("attn.kv_update"):
-        bidx = jnp.arange(B)
-        ck = cache["k"].at[bidx, :, pos_b].set(k[:, 0].astype(cache["k"].dtype))
-        cv = cache["v"].at[bidx, :, pos_b].set(v[:, 0].astype(cache["v"].dtype))
-    # masked single-query attention over the cache (memory-bound: jnp path)
+        ck = write_rows(cache["k"], layer, pos_b, k[:, 0])
+        cv = write_rows(cache["v"], layer, pos_b, v[:, 0])
     with jax.named_scope("attn.decode"):
-        G = cfg.n_heads // cfg.n_kv_heads
-        qh = q.reshape(B, 1, cfg.n_kv_heads, G, hd)
-        s = jnp.einsum("bqhgd,bhkd->bhgk", qh.astype(jnp.float32), ck.astype(jnp.float32))
-        s = s * (hd ** -0.5)
-        kpos = jnp.arange(ck.shape[2])
-        valid = kpos[None, :] <= pos_b[:, None]  # (B, S)
-        if cfg.sliding_window is not None:
-            valid &= kpos[None, :] > pos_b[:, None] - cfg.sliding_window
-        s = jnp.where(valid[:, None, None, :], s, -1e30)
-        p = jax.nn.softmax(s, axis=-1)
-        o = jnp.einsum("bhgk,bhkd->bhgd", p, cv.astype(jnp.float32))
+        if default_impl() == "pallas":
+            # reads the layer's blocks where they lie (XLA copies a layer out)
+            o = decode_attention(q[:, 0], ck, cv, layer, pos_b, window=cfg.sliding_window)
+        else:
+            o = _gqa_decode_xla(q, ck[layer], cv[layer], pos_b, cfg)
         o = o.reshape(B, 1, cfg.n_heads * hd).astype(x.dtype)
     with jax.named_scope("attn.out"):
         o = o @ params["wo"]
     return o, {"k": ck, "v": cv}
 
 
+def _gqa_decode_xla(q, ck, cv, pos_b, cfg):
+    """Masked single-query attention over one layer's cache (B, S, kv, hd)
+    in jnp (memory-bound): ``decode_attention``'s math, (B, kv, G, hd)."""
+    B, hd = q.shape[0], cfg.head_dim
+    G = cfg.n_heads // cfg.n_kv_heads
+    qh = q.reshape(B, 1, cfg.n_kv_heads, G, hd)
+    s = jnp.einsum("bqhgd,bkhd->bhgk", qh.astype(jnp.float32), ck.astype(jnp.float32))
+    s = s * (hd ** -0.5)
+    kpos = jnp.arange(ck.shape[1])
+    valid = kpos[None, :] <= pos_b[:, None]  # (B, S)
+    if cfg.sliding_window is not None:
+        valid &= kpos[None, :] > pos_b[:, None] - cfg.sliding_window
+    s = jnp.where(valid[:, None, None, :], s, -1e30)
+    p = jax.nn.softmax(s, axis=-1)
+    return jnp.einsum("bhgk,bkhd->bhgd", p, cv.astype(jnp.float32))
+
+
 def gqa_cache_init(cfg, batch, max_seq, dtype):
+    """(B, max_seq, kv_heads, hd): a position's K (or V) rows lie together."""
     hd = cfg.head_dim
     return {
-        "k": jnp.zeros((batch, cfg.n_kv_heads, max_seq, hd), dtype),
-        "v": jnp.zeros((batch, cfg.n_kv_heads, max_seq, hd), dtype),
+        "k": jnp.zeros((batch, max_seq, cfg.n_kv_heads, hd), dtype),
+        "v": jnp.zeros((batch, max_seq, cfg.n_kv_heads, hd), dtype),
     }
 
 
@@ -211,30 +233,33 @@ def mla_train(params, x, cfg, positions, use_kernel=True):
     return o.reshape(B, S, H * m.v_head_dim) @ params["wo"]
 
 
-def mla_decode(params, x, cache, cfg, position):
-    """Latent cache: {'c_kv': (B, max_seq, r), 'k_rope': (B, max_seq, dr)}."""
+def mla_decode(params, x, cache, layer, cfg, position):
+    """Latent cache: {'c_kv': (L, B, max_seq, r), 'k_rope': (L, B, max_seq,
+    dr)}, every layer's, written and read as ``gqa_decode``'s."""
     m = cfg.mla
     B = x.shape[0]
     H = cfg.n_heads
     pos_b = jnp.broadcast_to(jnp.asarray(position, jnp.int32), (B,))
     q_nope, q_rope, c_kv_new, k_rope_new = _mla_qkv(params, x, cfg, pos_b[:, None])
-    bidx = jnp.arange(B)
-    c = cache["c_kv"].at[bidx, pos_b].set(c_kv_new[:, 0].astype(cache["c_kv"].dtype))
-    kr = cache["k_rope"].at[bidx, pos_b].set(
-        k_rope_new[:, 0].astype(cache["k_rope"].dtype)
-    )
+    with jax.named_scope("attn.kv_update"):
+        cache = {"c_kv": write_rows(cache["c_kv"], layer, pos_b, c_kv_new[:, 0]),
+                 "k_rope": write_rows(cache["k_rope"], layer, pos_b, k_rope_new[:, 0])}
+    c, kr = cache["c_kv"][layer], cache["k_rope"][layer]
     # absorbed-matmul decode: reconstruct k_nope/v from latent (memory-bound)
     k_nope, v = _mla_expand_kv(params, c, cfg)  # (B, S, H, ·)
     scale = (m.qk_nope_head_dim + m.qk_rope_head_dim) ** -0.5
     s = jnp.einsum("bqhd,bkhd->bhqk", q_nope.astype(jnp.float32), k_nope.astype(jnp.float32))
-    s += jnp.einsum("bqhd,bkd->bhqk", q_rope.astype(jnp.float32), kr.astype(jnp.float32))
+    # the shared rotary key's scores as products summed: a dot would copy
+    # the layer's k_rope rows out of the stacked cache to read them
+    qr = q_rope[:, 0, :, None, :].astype(jnp.float32)  # (B, H, 1, dr)
+    s += (qr * kr[:, None].astype(jnp.float32)).sum(-1)[:, :, None, :]
     s *= scale
     valid = jnp.arange(c.shape[1])[None, :] <= pos_b[:, None]  # (B, S)
     s = jnp.where(valid[:, None, None, :], s, -1e30)
     p = jax.nn.softmax(s, axis=-1)
     o = jnp.einsum("bhqk,bkhd->bqhd", p, v.astype(jnp.float32))
     o = o.reshape(B, 1, H * m.v_head_dim).astype(x.dtype)
-    return o @ params["wo"], {"c_kv": c, "k_rope": kr}
+    return o @ params["wo"], cache
 
 
 def mla_cache_init(cfg, batch, max_seq, dtype):

@@ -172,6 +172,18 @@ def init_cache(cfg: ModelConfig, batch: int, max_seq: int, dtype=None):
     return cache
 
 
+def reset_states(cache, cfg: ModelConfig, slot):
+    """``cache`` with slot ``slot``'s recurrent states (Mamba, mLSTM, sLSTM)
+    zeroed, as ``init_cache`` makes them: a request seated in a used slot
+    starts from them. Attention rows need no reset: a slot attends only to
+    the positions it has written since its position went back to 0."""
+    stack = tuple(
+        c if mixer == "attn" else jax.tree.map(lambda a: a.at[:, slot].set(0), c)
+        for c, (mixer, _) in zip(cache["stack"], cfg.layer_kinds())
+    )
+    return dict(cache, stack=stack)
+
+
 def cache_specs(cfg: ModelConfig, rules, long_context: bool):
     s = {"stack": T.stack_cache_specs(cfg, rules, long_context)}
     if cfg.first_dense_layers:
@@ -194,21 +206,20 @@ def decode_step(params, cache, batch, position, cfg: ModelConfig, unroll: bool =
     mrope = batch.get("mrope_positions")
     new_cache = dict(cache)
     if cfg.first_dense_layers:
-        def pre_fn(x, inputs):
-            member, c = inputs
-            x, nc = T.member_decode(member, x, c, cfg, "attn", "mlp", position, mrope)
-            return x, nc
+        def pre_fn(carry, inputs):
+            x, c = carry
+            member, i = inputs
+            return T.member_decode(member, x, c, i, cfg, "attn", "mlp", position, mrope), None
+        carry = (x, cache["prefix"][0])
         if unroll:
-            outs = []
             for i in range(cfg.first_dense_layers):
-                sel = lambda a: a[i]
-                x, nc = pre_fn(x, (jax.tree.map(sel, params["prefix"][0]),
-                                   jax.tree.map(sel, cache["prefix"][0])))
-                outs.append(nc)
-            npc = jax.tree.map(lambda *xs: jnp.stack(xs), *outs)
+                carry, _ = pre_fn(carry, (jax.tree.map(lambda a: a[i], params["prefix"][0]), i))
         else:
-            x, npc = jax.lax.scan(pre_fn, x, (params["prefix"][0], cache["prefix"][0]))
-        new_cache["prefix"] = (npc,)
+            carry, _ = jax.lax.scan(
+                pre_fn, carry, (params["prefix"][0], jnp.arange(cfg.first_dense_layers))
+            )
+        x, pc = carry
+        new_cache["prefix"] = (pc,)
     x, nsc = T.stack_decode(params["stack"], x, cache["stack"], cfg, position, mrope, unroll)
     new_cache["stack"] = nsc
     h = _norm_f(cfg)(params["final_norm"], x)
@@ -239,16 +250,13 @@ def decode_step_staged(params, cache, batch, position, cfg: ModelConfig):
     mrope = batch.get("mrope_positions")
     new_cache = dict(cache)
     if cfg.first_dense_layers:
-        outs = []
+        pc = cache["prefix"][0]
         for i in range(cfg.first_dense_layers):
-            sel = lambda a: a[i]
-            x, nc = T.member_decode(
-                jax.tree.map(sel, params["prefix"][0]), x,
-                jax.tree.map(sel, cache["prefix"][0]), cfg, "attn", "mlp",
-                position, mrope,
+            x, pc = T.member_decode(
+                jax.tree.map(lambda a: a[i], params["prefix"][0]), x, pc, i,
+                cfg, "attn", "mlp", position, mrope,
             )
-            outs.append(nc)
-        new_cache["prefix"] = (jax.tree.map(lambda *xs: jnp.stack(xs), *outs),)
+        new_cache["prefix"] = (pc,)
     x, nsc = yield from T.stack_decode_staged(
         params["stack"], x, cache["stack"], cfg, position, mrope
     )

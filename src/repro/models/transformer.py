@@ -99,22 +99,30 @@ def member_train(params, x, cfg, mixer, ffn, positions, mrope_positions, use_ker
     return x, aux
 
 
-def member_decode_mixer(params, x, cache, cfg, mixer, position, mrope_positions):
+def member_decode_mixer(params, x, cache, layer, cfg, mixer, position, mrope_positions):
     """The mixer half of one decode member: pre-norm mixer + residual.
-    Returns (x, new_cache) — the FFN half (if any) applies on top."""
+    ``cache`` is the member's, every layer's (leading axis): attention
+    writes the new token's rows into layer ``layer`` and reads the layer
+    where it lies; a recurrent mixer's new state replaces its layer's.
+    Returns (x, cache) — the FFN half (if any) applies on top."""
     norm = _norm(cfg)
     h = norm(params["norm1"], x)
     if mixer == "attn":
         if cfg.attention == "mla":
-            mx, cache = A.mla_decode(params["mixer"], h, cache, cfg, position)
+            mx, cache = A.mla_decode(params["mixer"], h, cache, layer, cfg, position)
         else:
-            mx, cache = A.gqa_decode(params["mixer"], h, cache, cfg, position, mrope_positions)
-    elif mixer == "mamba":
-        mx, cache = MB.mamba_decode(params["mixer"], h, cache, cfg)
+            mx, cache = A.gqa_decode(
+                params["mixer"], h, cache, layer, cfg, position, mrope_positions
+            )
+        return x + mx, cache
+    state = jax.tree.map(lambda a: a[layer], cache)
+    if mixer == "mamba":
+        mx, state = MB.mamba_decode(params["mixer"], h, state, cfg)
     elif mixer == "mlstm":
-        mx, cache = XL.mlstm_decode(params["mixer"], h, cache, cfg)
+        mx, state = XL.mlstm_decode(params["mixer"], h, state, cfg)
     else:
-        mx, cache = XL.slstm_decode(params["mixer"], h, cache, cfg)
+        mx, state = XL.slstm_decode(params["mixer"], h, state, cfg)
+    cache = jax.tree.map(lambda c, s: c.at[layer].set(s.astype(c.dtype)), cache, state)
     return x + mx, cache
 
 
@@ -125,14 +133,16 @@ def mixer_decode_jit(cfg, mixer):
     though the generator itself is eager Python. mrope-free (token serving);
     callers with mrope positions fall back to the eager form."""
 
-    def fn(params, x, cache, position):
-        return member_decode_mixer(params, x, cache, cfg, mixer, position, None)
+    def fn(params, x, cache, layer, position):
+        return member_decode_mixer(params, x, cache, layer, cfg, mixer, position, None)
 
     return jax.jit(fn)
 
 
-def member_decode(params, x, cache, cfg, mixer, ffn, position, mrope_positions):
-    x, cache = member_decode_mixer(params, x, cache, cfg, mixer, position, mrope_positions)
+def member_decode(params, x, cache, layer, cfg, mixer, ffn, position, mrope_positions):
+    x, cache = member_decode_mixer(
+        params, x, cache, layer, cfg, mixer, position, mrope_positions
+    )
     if ffn != "none":
         h2 = _norm(cfg)(params["norm2"], x)
         if ffn == "moe":
@@ -223,31 +233,34 @@ def stack_train(stack_params, x, cfg, positions, mrope_positions=None, use_kerne
 
 def stack_decode(stack_params, x, caches, cfg, position, mrope_positions=None,
                  unroll: bool = False):
+    """One token through the stack. The stacked ``caches`` ride in the layer
+    loop's carry: each layer writes its update into its layer of them and
+    reads that layer where it lies, so a donated cache is updated in place.
+    Returns (x, new_caches)."""
     pattern = cfg.layer_kinds()
 
-    def group_fn(x, inputs):
-        group_params, group_cache = inputs
-        new_caches = []
+    def group_fn(carry, inputs):
+        x, caches = carry
+        group_params, g = inputs
+        caches = list(caches)
         for mi, (mixer, ffn) in enumerate(pattern):
-            x, nc = member_decode(
-                group_params[mi], x, group_cache[mi], cfg, mixer, ffn, position, mrope_positions
+            x, caches[mi] = member_decode(
+                group_params[mi], x, caches[mi], g, cfg, mixer, ffn, position, mrope_positions
             )
-            new_caches.append(nc)
-        return x, tuple(new_caches)
+        return (x, tuple(caches)), None
 
-    if unroll:
-        outs = []
-        for g in range(cfg.n_groups):
-            sel = lambda a: a[g]
-            x, nc = group_fn(x, (jax.tree.map(sel, stack_params), jax.tree.map(sel, caches)))
-            outs.append(nc)
-        new_caches = jax.tree.map(lambda *xs: jnp.stack(xs), *outs)
-        return x, new_caches
-
-    # the loop's own slicing of the stacked caches and their write-back
+    # the loop and its carry of the stacked caches
     with jax.named_scope("decode.layers"):
-        x, new_caches = jax.lax.scan(group_fn, x, (stack_params, caches))
-    return x, new_caches
+        if unroll:
+            for g in range(cfg.n_groups):
+                (x, caches), _ = group_fn(
+                    (x, caches), (jax.tree.map(lambda a: a[g], stack_params), g)
+                )
+        else:
+            (x, caches), _ = jax.lax.scan(
+                group_fn, (x, tuple(caches)), (stack_params, jnp.arange(cfg.n_groups))
+            )
+    return x, caches
 
 
 def stack_decode_staged(stack_params, x, caches, cfg, position, mrope_positions=None):
@@ -264,28 +277,24 @@ def stack_decode_staged(stack_params, x, caches, cfg, position, mrope_positions=
     (eager fallback when mrope positions are present); everything outside
     the MoE members is the same math as ``stack_decode(unroll=True)``.
 
-    Returns (x, new_caches) via StopIteration.value, caches restacked over
-    the group axis like the unroll path.
+    Returns (x, new_caches) via StopIteration.value: each mixer writes its
+    layer of the stacked caches, as in ``stack_decode``.
     """
     pattern = cfg.layer_kinds()
     norm = _norm(cfg)
-    outs = []
+    caches = list(caches)
     for g in range(cfg.n_groups):
-        sel = lambda a: a[g]
-        group_params = jax.tree.map(sel, stack_params)
-        group_cache = jax.tree.map(sel, caches)
-        new_caches = []
+        group_params = jax.tree.map(lambda a: a[g], stack_params)
         for mi, (mixer, ffn) in enumerate(pattern):
             if mrope_positions is None:
-                x, nc = mixer_decode_jit(cfg, mixer)(
-                    group_params[mi], x, group_cache[mi], position
+                x, caches[mi] = mixer_decode_jit(cfg, mixer)(
+                    group_params[mi], x, caches[mi], g, position
                 )
             else:
-                x, nc = member_decode_mixer(
-                    group_params[mi], x, group_cache[mi], cfg, mixer,
+                x, caches[mi] = member_decode_mixer(
+                    group_params[mi], x, caches[mi], g, cfg, mixer,
                     position, mrope_positions,
                 )
-            new_caches.append(nc)
             if ffn == "moe":
                 h2 = norm(group_params[mi]["norm2"], x)
                 y = yield (group_params[mi]["ffn"], h2)
@@ -296,9 +305,7 @@ def stack_decode_staged(stack_params, x, caches, cfg, position, mrope_positions=
                     group_params[mi]["ffn"], h2,
                     act=jax.nn.silu if cfg.mlp_gated else jax.nn.gelu,
                 )
-        outs.append(tuple(new_caches))
-    new_caches = jax.tree.map(lambda *xs: jnp.stack(xs), *outs)
-    return x, new_caches
+    return x, tuple(caches)
 
 
 def stack_cache_init(cfg, batch, max_seq, dtype):
@@ -328,8 +335,8 @@ def stack_cache_specs(cfg, rules, long_context: bool):
                 })
             else:
                 specs.append({
-                    "k": P(None, b, None, t, None),  # (G, B, kvh, S, hd)
-                    "v": P(None, b, None, t, None),
+                    "k": P(None, b, t, None, None),  # (G, B, S, kvh, hd)
+                    "v": P(None, b, t, None, None),
                 })
         elif mixer == "mamba":
             specs.append({

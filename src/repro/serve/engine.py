@@ -68,9 +68,18 @@ class Engine:
         self.positions = np.zeros(batch_slots, np.int32)  # next write index
         self.pending_tok = np.zeros(batch_slots, np.int32)
         self.slot_req: dict[int, Request] = {}
+        # the cache is donated: each step updates it in place and returns
+        # it, and the previous ``self.cache`` is then deleted, so nothing
+        # may hold it across a step
         self._step = jax.jit(
-            lambda p, c, b, pos: M.decode_step(p, c, b, pos, self.cfg)
+            lambda p, c, b, pos: M.decode_step(p, c, b, pos, self.cfg),
+            donate_argnums=(1,),
         )
+        self._reset_states = None
+        if any(mixer != "attn" for mixer, _ in cfg.layer_kinds()):
+            self._reset_states = jax.jit(
+                lambda c, slot: M.reset_states(c, self.cfg, slot), donate_argnums=(0,)
+            )
         self.stats = EngineStats()
         self._prefill_rid = None  # the request ``admit`` is prefilling
 
@@ -112,6 +121,8 @@ class Engine:
             req.admitted_at = time.perf_counter()
             self.slot_req[slot] = req
             self.positions[slot] = 0
+            if self._reset_states is not None:  # the slot's last request's state
+                self.cache = self._reset_states(self.cache, slot)
             # prefill: feed prompt tokens through the cached decode path;
             # the other slots advance with their own pending tokens (no
             # stalls).

@@ -120,3 +120,30 @@ def test_flash_grad_vs_ref(causal, window):
         q, k, v, causal=causal, window=window)), argnums=(0, 1, 2))(q, k, v)
     for g, w in zip(got, want):
         np.testing.assert_allclose(np.asarray(g), np.asarray(w), rtol=1e-4, atol=1e-4)
+
+
+@pytest.mark.parametrize("window,block", [(None, 16), (None, 64), (7, 16)])
+def test_decode_attention_vs_ref(window, block):
+    """The decode kernel (interpret mode) attends from each slot's query
+    over one layer of a stacked cache, up to the slot's position and
+    within the window, as the materialized softmax does. Inputs lie on
+    the bf16 grid, so only the kernel's bf16 probabilities round:
+    |v| <= 1 bounds the difference by 2**-8."""
+    from repro.kernels.flash_attention.decode import decode_attention
+
+    rng = np.random.default_rng(3)
+    L, B, S, kv, hd, H = 3, 4, 64, 2, 16, 8
+    grid = lambda *shape: jnp.asarray(rng.uniform(-1, 1, shape), jnp.bfloat16).astype(jnp.float32)
+    kc, vc, q = grid(L, B, S, kv, hd), grid(L, B, S, kv, hd), grid(B, H, hd)
+    pos = jnp.asarray([0, 5, 33, 63], jnp.int32)
+    got = decode_attention(q, kc, vc, 1, pos, window=window, block=block, interpret=True)
+
+    qh = q.reshape(B, kv, H // kv, hd)
+    s = jnp.einsum("bhgd,bkhd->bhgk", qh, kc[1], precision="highest") * hd ** -0.5
+    kpos = jnp.arange(S)[None]
+    valid = kpos <= pos[:, None]
+    if window is not None:
+        valid &= kpos > pos[:, None] - window
+    p = jax.nn.softmax(jnp.where(valid[:, None, None], s, -1e30), axis=-1)
+    want = jnp.einsum("bhgk,bkhd->bhgd", p, vc[1], precision="highest").reshape(B, H, hd)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2 ** -8, rtol=0)

@@ -120,3 +120,62 @@ def test_rdma_ring_compiles(topo, monkeypatch):
     # off the chip the backend refuses the compiled ring; steer it here
     monkeypatch.setattr(pf, "_on_tpu", lambda: True)
     assert "tpu_custom_call" in _compiled_text(ring, x)
+
+
+def test_decode_attention_compiles(one_chip):
+    """The decode kernel over the serve cell's stacked f32 cache (Mixtral
+    8x7B heads, 2 layers, 16 slots of 2048 positions): the kernel reads
+    the cache through a bitcast, with no copy and no scratch in HBM."""
+    from repro.kernels.flash_attention.decode import decode_attention
+
+    L, B, S, kv, hd, H = 2, 16, 2048, 8, 128, 32
+    cache = jax.ShapeDtypeStruct((L, B, S, kv, hd), jnp.float32, sharding=one_chip)
+    compiled = jax.jit(lambda q, k, v, layer, pos: decode_attention(
+        q, k, v, layer, pos, interpret=False)).lower(
+        jax.ShapeDtypeStruct((B, H, hd), jnp.float32, sharding=one_chip), cache, cache,
+        jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip),
+        jax.ShapeDtypeStruct((B,), jnp.int32, sharding=one_chip)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes == 0
+
+
+def test_serve_decode_step_updates_cache_in_place(one_chip, monkeypatch):
+    """The engine's decode step (cache donated) at the serve cell's shapes:
+    Mixtral 8x7B widths, 2 layers, 16 slots of 2048 f32 positions. Every
+    cache leaf shares its buffer with an output, and outside fusions no
+    instruction makes an array of a layer's or of the stack's K/V dims, in
+    any axis order, but the in-place row update; the attention is the
+    kernel."""
+    import dataclasses
+    import functools
+
+    from _hlo import aliased_parameter_dims, materialized
+    from repro.configs import get_config
+    from repro.kernels.flash_attention import decode
+    from repro.models import attention
+    from repro.models import model as M
+
+    # the attention path a TPU takes (the backend here is the CPU)
+    monkeypatch.setattr(attention, "default_impl", lambda: "pallas")
+    monkeypatch.setattr(attention, "decode_attention",
+                        functools.partial(decode.decode_attention, interpret=False))
+    cfg = dataclasses.replace(get_config("mixtral-8x7b"), n_layers=2)
+    B, S = 16, 2048
+    on = lambda tree: jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip), tree)
+    params = on(jax.eval_shape(lambda: M.init_params(jax.random.key(0), cfg)))
+    cache = on(jax.eval_shape(lambda: M.init_cache(cfg, B, S, dtype=jnp.float32)))
+    vec = jax.ShapeDtypeStruct((B,), jnp.int32, sharding=one_chip)
+    step = jax.jit(lambda p, c, b, pos: M.decode_step(p, c, b, pos, cfg),
+                   donate_argnums=(1,))  # as ``Engine`` jits it
+    text = step.lower(params, cache, {"token": vec}, vec).compile().as_text()
+
+    leaves = jax.tree.leaves(cache)
+    assert aliased_parameter_dims(text) == sorted(leaf.shape for leaf in leaves)
+    size = lambda dims: tuple(sorted(d for d in dims if d != 1))
+    kv_sizes = {size(s) for leaf in leaves for s in (leaf.shape, leaf.shape[1:])}
+    made = [m for m in materialized(text) if size(m[1]) in kv_sizes]
+    allowed = {"parameter", "get-tuple-element", "bitcast", "fusion:scatter"}
+    assert [m for m in made if m[2] not in allowed] == []
+    assert any(kind == "fusion:scatter" for _, _, kind in made)
+    assert 'custom_call_target="tpu_custom_call"' in text
