@@ -117,9 +117,9 @@ def decode_vs_forward(eng, prompt) -> float:
 
     cfg, params = eng.cfg, eng.params
     cache = M.init_cache(cfg, eng.slots, eng.max_seq, dtype=jnp.float32)
-    dec = []
+    step, dec = _decode_step(cfg), []
     for pos, tok in enumerate(prompt):
-        logits, cache = eng._step(
+        logits, cache = step(
             params, cache, {"token": jnp.full((eng.slots,), tok, jnp.int32)},
             jnp.full((eng.slots,), pos, jnp.int32))
         dec.append(np.asarray(logits[0], np.float32))

@@ -4,6 +4,7 @@ from repro.configs.base import (
     ModelConfig,
     MoEConfig,
     MLAConfig,
+    YaRNConfig,
     MambaConfig,
     XLSTMConfig,
     ShapeConfig,
@@ -20,6 +21,7 @@ __all__ = [
     "ModelConfig",
     "MoEConfig",
     "MLAConfig",
+    "YaRNConfig",
     "MambaConfig",
     "XLSTMConfig",
     "ShapeConfig",

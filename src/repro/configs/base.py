@@ -22,15 +22,34 @@ class MoEConfig:
     layer_period: int = 1      # MoE every k-th layer
     aux_loss_weight: float = 0.01
     capacity_factor: float = 1.25  # sparse-dispatch buffer headroom
+    # (first, count): the routed experts this chip holds, as one chip of an
+    # expert-parallel deployment does; None holds all ``num_experts``
+    held_experts: Optional[tuple[int, int]] = None
+
+    @property
+    def n_held(self) -> int:
+        return self.num_experts if self.held_experts is None else self.held_experts[1]
 
 
 @dataclasses.dataclass(frozen=True)
 class MLAConfig:
-    q_lora_rank: int = 1536
+    q_lora_rank: Optional[int] = 1536  # None: a direct query projection
     kv_lora_rank: int = 512
     qk_nope_head_dim: int = 128
     qk_rope_head_dim: int = 64
     v_head_dim: int = 128
+
+
+@dataclasses.dataclass(frozen=True)
+class YaRNConfig:
+    """YaRN rotary scaling (arXiv:2309.00071) as DeepSeek-V2 publishes it
+    (``rope_scaling`` of type ``yarn``)."""
+    factor: float = 40.0
+    original_max_position: int = 4096
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
 
 
 @dataclasses.dataclass(frozen=True)
@@ -62,8 +81,10 @@ class ModelConfig:
     sliding_window: Optional[int] = None
     rope: str = "rope"  # rope | mrope | none
     rope_theta: float = 10000.0
+    yarn: Optional[YaRNConfig] = None
     mrope_sections: tuple = (16, 24, 24)
     norm: str = "rmsnorm"  # rmsnorm | layernorm | nonparametric
+    norm_eps: float = 1e-5
     moe: Optional[MoEConfig] = None
     mla: Optional[MLAConfig] = None
     mamba: Optional[MambaConfig] = None
@@ -133,11 +154,15 @@ class ModelConfig:
                 if self.attention == "mla":
                     m = self.mla
                     qh = m.qk_nope_head_dim + m.qk_rope_head_dim
-                    total += d * m.q_lora_rank + m.q_lora_rank * self.n_heads * qh
+                    if m.q_lora_rank is None:
+                        total += d * self.n_heads * qh
+                    else:
+                        total += d * m.q_lora_rank + m.q_lora_rank * self.n_heads * qh
+                        total += m.q_lora_rank
                     total += d * (m.kv_lora_rank + m.qk_rope_head_dim)
                     total += m.kv_lora_rank * self.n_heads * (m.qk_nope_head_dim + m.v_head_dim)
                     total += self.n_heads * m.v_head_dim * d
-                    total += m.q_lora_rank + m.kv_lora_rank
+                    total += m.kv_lora_rank
                 else:
                     total += d * self.n_heads * hd + 2 * d * self.n_kv_heads * hd
                     total += self.n_heads * hd * d
@@ -207,6 +232,7 @@ def cell_supported(arch_name: str, shape_name: str) -> tuple[bool, str]:
 ARCH_IDS = [
     "mixtral-8x7b",
     "deepseek-v3-671b",
+    "deepseek-v2-lite",
     "jamba-1.5-large-398b",
     "musicgen-large",
     "qwen2-vl-7b",
@@ -220,6 +246,7 @@ ARCH_IDS = [
 _MOD = {
     "mixtral-8x7b": "mixtral_8x7b",
     "deepseek-v3-671b": "deepseek_v3_671b",
+    "deepseek-v2-lite": "deepseek_v2_lite",
     "jamba-1.5-large-398b": "jamba_1_5_large_398b",
     "musicgen-large": "musicgen_large",
     "qwen2-vl-7b": "qwen2_vl_7b",

@@ -13,6 +13,7 @@ from jax.sharding import PartitionSpec as P
 
 from repro.models import layers as L
 from repro.kernels.flash_attention.decode import decode_attention
+from repro.kernels.flash_attention.mla_decode import mla_decode_attention
 from repro.kernels.flash_attention.ops import default_impl, gqa_attention
 from repro.kernels.flash_attention.ref import attention_ref
 
@@ -132,20 +133,17 @@ def gqa_cache_init(cfg, batch, max_seq, dtype):
 
 
 # ================================================================= MLA
-# DeepSeek-V3 Multi-head Latent Attention: queries via a low-rank path,
-# keys/values reconstructed from a compressed latent c_kv (cached) plus a
-# shared rotary key k_rope. Decode caches ONLY (c_kv, k_rope).
+# DeepSeek Multi-head Latent Attention: queries through a low-rank path
+# (DeepSeek-V3) or one projection (DeepSeek-V2-Lite); keys and values
+# reconstructed from a compressed latent c_kv plus a rotary key k_rope that
+# every head shares. Decode caches only the latent row [c_kv | k_rope].
 def mla_init(key, cfg, dtype):
     d = cfg.d_model
     m = cfg.mla
     ks = jax.random.split(key, 7)
     s = d ** -0.5
     qh = m.qk_nope_head_dim + m.qk_rope_head_dim
-    return {
-        "wq_a": L.truncated_normal(ks[0], (d, m.q_lora_rank), dtype, s),
-        "wq_b": L.truncated_normal(
-            ks[1], (m.q_lora_rank, cfg.n_heads * qh), dtype, m.q_lora_rank ** -0.5
-        ),
+    p = {
         "wkv_a": L.truncated_normal(
             ks[2], (d, m.kv_lora_rank + m.qk_rope_head_dim), dtype, s
         ),
@@ -158,35 +156,65 @@ def mla_init(key, cfg, dtype):
         "wo": L.truncated_normal(
             ks[4], (cfg.n_heads * m.v_head_dim, d), dtype, (cfg.n_heads * m.v_head_dim) ** -0.5
         ),
-        "q_norm": L.rmsnorm_init(m.q_lora_rank, dtype),
         "kv_norm": L.rmsnorm_init(m.kv_lora_rank, dtype),
     }
+    if m.q_lora_rank is None:
+        p["wq"] = L.truncated_normal(ks[0], (d, cfg.n_heads * qh), dtype, s)
+    else:
+        p["wq_a"] = L.truncated_normal(ks[0], (d, m.q_lora_rank), dtype, s)
+        p["wq_b"] = L.truncated_normal(
+            ks[1], (m.q_lora_rank, cfg.n_heads * qh), dtype, m.q_lora_rank ** -0.5
+        )
+        p["q_norm"] = L.rmsnorm_init(m.q_lora_rank, dtype)
+    return p
 
 
 def mla_specs(cfg, rules):
-    return {
-        "wq_a": P(None, None),
-        "wq_b": rules.attn_in((0, 0)),
+    s = {
         "wkv_a": P(None, None),
         "wkv_b": rules.attn_in((0, 0)),
         "wo": rules.attn_out((0, 0)),
-        "q_norm": {"scale": P(None)},
         "kv_norm": {"scale": P(None)},
     }
+    if cfg.mla.q_lora_rank is None:
+        s["wq"] = rules.attn_in((0, 0))
+    else:
+        s.update(wq_a=P(None, None), wq_b=rules.attn_in((0, 0)), q_norm={"scale": P(None)})
+    return s
+
+
+def mla_scale(cfg) -> float:
+    """The softmax scale: 1/sqrt(qk head dim), times YaRN's mscale squared
+    where the configuration sets ``mscale_all_dim`` (DeepSeek-V2)."""
+    m = cfg.mla
+    scale = (m.qk_nope_head_dim + m.qk_rope_head_dim) ** -0.5
+    y = cfg.yarn
+    if y is not None and y.mscale_all_dim:
+        scale *= L.yarn_mscale(y.factor, y.mscale_all_dim) ** 2
+    return scale
+
+
+def _mla_rope(x, positions, cfg):
+    """DeepSeek's rotary embedding: interleaved pairs, YaRN where set."""
+    return L.apply_rope(x, positions, cfg.rope_theta, cfg.yarn, interleaved=True)
 
 
 def _mla_qkv(params, x, cfg, positions):
     B, S, _ = x.shape
     m = cfg.mla
     H = cfg.n_heads
-    q_lat = L.rmsnorm(params["q_norm"], x @ params["wq_a"])
-    q = (q_lat @ params["wq_b"]).reshape(B, S, H, m.qk_nope_head_dim + m.qk_rope_head_dim)
+    if m.q_lora_rank is None:
+        q = x @ params["wq"]
+    else:
+        q_lat = L.rmsnorm(params["q_norm"], x @ params["wq_a"], cfg.norm_eps)
+        q = q_lat @ params["wq_b"]
+    q = q.reshape(B, S, H, m.qk_nope_head_dim + m.qk_rope_head_dim)
     q_nope, q_rope = jnp.split(q, [m.qk_nope_head_dim], axis=-1)
-    q_rope = L.apply_rope(q_rope, positions, cfg.rope_theta)
+    q_rope = _mla_rope(q_rope, positions, cfg)
     kv_a = x @ params["wkv_a"]
     c_kv, k_rope = jnp.split(kv_a, [m.kv_lora_rank], axis=-1)
-    c_kv = L.rmsnorm(params["kv_norm"], c_kv)
-    k_rope = L.apply_rope(k_rope[:, :, None, :], positions, cfg.rope_theta)  # 1 shared head
+    c_kv = L.rmsnorm(params["kv_norm"], c_kv, cfg.norm_eps)
+    k_rope = _mla_rope(k_rope[:, :, None, :], positions, cfg)  # 1 shared head
     return q_nope, q_rope, c_kv, k_rope[:, :, 0, :]
 
 
@@ -210,9 +238,12 @@ def mla_train(params, x, cfg, positions, use_kernel=True):
         [k_nope, jnp.broadcast_to(k_rope[:, :, None, :], (B, S, H, m.qk_rope_head_dim))],
         axis=-1,
     )
-    scale = (m.qk_nope_head_dim + m.qk_rope_head_dim) ** -0.5
-    # v head dim differs from qk head dim -> pad v for the kernel path
     qk_hd = m.qk_nope_head_dim + m.qk_rope_head_dim
+    scale = qk_hd ** -0.5
+    gain = mla_scale(cfg) / scale  # the attention paths below scale by 1/sqrt(qk_hd)
+    if gain != 1.0:
+        q = q * jnp.asarray(gain, q.dtype)
+    # v head dim differs from qk head dim -> pad v for the kernel path
     if m.v_head_dim == qk_hd and use_kernel:
         o = gqa_attention(q, k, v, causal=True, use_kernel=True)
     else:
@@ -234,37 +265,62 @@ def mla_train(params, x, cfg, positions, use_kernel=True):
 
 
 def mla_decode(params, x, cache, layer, cfg, position):
-    """Latent cache: {'c_kv': (L, B, max_seq, r), 'k_rope': (L, B, max_seq,
-    dr)}, every layer's, written and read as ``gqa_decode``'s."""
+    """Absorbed MLA decode. x: (B, 1, d); cache: {'latent': (L, B, max_seq,
+    w)}, every layer's rows ``[c_kv | k_rope | 0]`` (``mla_cache_init``),
+    written and read as ``gqa_decode``'s. The key up-projection W_UK is absorbed into the
+    query (``q_nope . W_UK``), so scores are taken against the latent rows
+    themselves, and the value up-projection W_UV applies to the
+    attention's latent output: no cached position is expanded to per-head
+    keys or values. Returns (out, cache)."""
     m = cfg.mla
     B = x.shape[0]
-    H = cfg.n_heads
+    H, r, nope = cfg.n_heads, m.kv_lora_rank, m.qk_nope_head_dim
     pos_b = jnp.broadcast_to(jnp.asarray(position, jnp.int32), (B,))
-    q_nope, q_rope, c_kv_new, k_rope_new = _mla_qkv(params, x, cfg, pos_b[:, None])
+    with jax.named_scope("attn.qkv"):
+        q_nope, q_rope, c_kv_new, k_rope_new = _mla_qkv(params, x, cfg, pos_b[:, None])
+    wkv_b = params["wkv_b"].reshape(r, H, nope + m.v_head_dim)
+    pad = cache["latent"].shape[-1] - r - m.qk_rope_head_dim
+    with jax.named_scope("attn.absorb"):
+        q_lat = jnp.einsum("bhn,rhn->bhr", q_nope[:, 0], wkv_b[..., :nope],
+                           preferred_element_type=jnp.float32)
+        q = jnp.concatenate([q_lat, q_rope[:, 0].astype(jnp.float32),
+                             jnp.zeros((B, H, pad), jnp.float32)], axis=-1)
     with jax.named_scope("attn.kv_update"):
-        cache = {"c_kv": write_rows(cache["c_kv"], layer, pos_b, c_kv_new[:, 0]),
-                 "k_rope": write_rows(cache["k_rope"], layer, pos_b, k_rope_new[:, 0])}
-    c, kr = cache["c_kv"][layer], cache["k_rope"][layer]
-    # absorbed-matmul decode: reconstruct k_nope/v from latent (memory-bound)
-    k_nope, v = _mla_expand_kv(params, c, cfg)  # (B, S, H, ·)
-    scale = (m.qk_nope_head_dim + m.qk_rope_head_dim) ** -0.5
-    s = jnp.einsum("bqhd,bkhd->bhqk", q_nope.astype(jnp.float32), k_nope.astype(jnp.float32))
-    # the shared rotary key's scores as products summed: a dot would copy
-    # the layer's k_rope rows out of the stacked cache to read them
-    qr = q_rope[:, 0, :, None, :].astype(jnp.float32)  # (B, H, 1, dr)
-    s += (qr * kr[:, None].astype(jnp.float32)).sum(-1)[:, :, None, :]
-    s *= scale
-    valid = jnp.arange(c.shape[1])[None, :] <= pos_b[:, None]  # (B, S)
-    s = jnp.where(valid[:, None, None, :], s, -1e30)
+        row = jnp.concatenate([c_kv_new[:, 0], k_rope_new[:, 0],
+                               jnp.zeros((B, pad), c_kv_new.dtype)], axis=-1)
+        latent = write_rows(cache["latent"], layer, pos_b, row)
+    with jax.named_scope("attn.decode"):
+        if default_impl() == "pallas":
+            # reads the layer's blocks where they lie (XLA copies a layer out)
+            o = mla_decode_attention(q, latent, layer, pos_b, r=r, scale=mla_scale(cfg))
+        else:
+            o = _mla_decode_xla(q, latent[layer], pos_b, r, mla_scale(cfg))
+    with jax.named_scope("attn.absorb"):
+        o = jnp.einsum("bhr,rhv->bhv", o.astype(x.dtype), wkv_b[..., nope:])
+    with jax.named_scope("attn.out"):
+        o = o.reshape(B, 1, H * m.v_head_dim) @ params["wo"]
+    return o, {"latent": latent}
+
+
+def _mla_decode_xla(q, latent, pos_b, r, scale):
+    """``mla_decode_attention``'s math in jnp: absorbed queries q (B, H, w)
+    over one layer's latent rows (B, S, w); (B, H, r). Scores
+    and outputs are products summed: a dot would copy the layer's rows out
+    of the stacked cache to read them."""
+    lat = latent[:, None].astype(jnp.float32)  # (B, 1, S, r + dr)
+    s = (q[:, :, None, :] * lat).sum(-1) * scale  # (B, H, S)
+    valid = jnp.arange(latent.shape[1])[None, :] <= pos_b[:, None]  # (B, S)
+    s = jnp.where(valid[:, None, :], s, -1e30)
     p = jax.nn.softmax(s, axis=-1)
-    o = jnp.einsum("bhqk,bkhd->bqhd", p, v.astype(jnp.float32))
-    o = o.reshape(B, 1, H * m.v_head_dim).astype(x.dtype)
-    return o @ params["wo"], cache
+    return (p[..., None] * lat[..., :r]).sum(2)
 
 
 def mla_cache_init(cfg, batch, max_seq, dtype):
+    """(B, max_seq, w): a position's latent row ``[c_kv | k_rope]``, padded
+    with zeros to ``w``, the next multiple of 128. A TPU stores the
+    unpadded rows' two minor dims transposed, which a kernel reading rows
+    would have to copy every step; padded, they lie row by row in the
+    same bytes as the unpadded rows would in that order."""
     m = cfg.mla
-    return {
-        "c_kv": jnp.zeros((batch, max_seq, m.kv_lora_rank), dtype),
-        "k_rope": jnp.zeros((batch, max_seq, m.qk_rope_head_dim), dtype),
-    }
+    w = -(-(m.kv_lora_rank + m.qk_rope_head_dim) // 128) * 128
+    return {"latent": jnp.zeros((batch, max_seq, w), dtype)}

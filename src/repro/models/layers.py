@@ -6,6 +6,8 @@ plus specs(cfg, rules) -> PartitionSpec tree mirroring params.
 
 from __future__ import annotations
 
+import math
+
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
@@ -62,18 +64,56 @@ def norm_specs(kind: str):
 
 
 # ------------------------------------------------------------------ RoPE
-def rope_freqs(head_dim: int, theta: float = 10000.0):
-    return 1.0 / (theta ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim))
+def yarn_range(head_dim: int, theta: float, yarn) -> tuple[int, int]:
+    """The rotary pairs over which YaRN ramps from the original frequency
+    (pairs up to ``low``) to the frequency divided by the factor (pairs
+    from ``high``): the pairs that turn ``beta_fast`` and ``beta_slow``
+    times over the original context, as DeepSeek-V2's
+    ``yarn_find_correction_range`` computes them."""
+    def dim(turns):
+        return (head_dim * math.log(yarn.original_max_position / (turns * 2 * math.pi))
+                / (2 * math.log(theta)))
+
+    return (max(math.floor(dim(yarn.beta_fast)), 0),
+            min(math.ceil(dim(yarn.beta_slow)), head_dim - 1))
 
 
-def apply_rope(x: jax.Array, positions: jax.Array, theta: float = 10000.0):
-    """x: (..., seq, heads, head_dim); positions: (..., seq) int."""
+def yarn_mscale(factor: float, mscale: float) -> float:
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def rope_freqs(head_dim: int, theta: float = 10000.0, yarn=None):
+    freqs = 1.0 / (theta ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim))
+    if yarn is None:
+        return freqs
+    low, high = yarn_range(head_dim, theta, yarn)
+    ramp = jnp.clip((jnp.arange(head_dim // 2, dtype=jnp.float32) - low)
+                    / max(high - low, 1e-3), 0.0, 1.0)
+    return freqs / yarn.factor * ramp + freqs * (1.0 - ramp)
+
+
+def apply_rope(x: jax.Array, positions: jax.Array, theta: float = 10000.0,
+               yarn=None, interleaved: bool = False):
+    """x: (..., seq, heads, head_dim); positions: (..., seq) int.
+
+    Pair ``i`` turns by ``position * rope_freqs(...)[i]``. The pairs are
+    the two halves of the head, or with ``interleaved`` neighbouring
+    columns (2i, 2i + 1), returned as DeepSeek's published code returns
+    them: the rotated first members, then the second. ``yarn`` scales the
+    frequencies and, by ``mscale / mscale_all_dim``, cos and sin."""
     hd = x.shape[-1]
-    freqs = rope_freqs(hd, theta)  # (hd/2,)
+    freqs = rope_freqs(hd, theta, yarn)  # (hd/2,)
     ang = positions[..., None].astype(jnp.float32) * freqs  # (..., seq, hd/2)
     ang = ang[..., None, :]  # broadcast over heads
     cos, sin = jnp.cos(ang), jnp.sin(ang)
-    x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
+    if yarn is not None and yarn.mscale != yarn.mscale_all_dim:
+        m = yarn_mscale(yarn.factor, yarn.mscale) / yarn_mscale(yarn.factor, yarn.mscale_all_dim)
+        cos, sin = cos * m, sin * m
+    xf = x.astype(jnp.float32)
+    if interleaved:
+        x1, x2 = xf[..., 0::2], xf[..., 1::2]
+    else:
+        x1, x2 = jnp.split(xf, 2, axis=-1)
     out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
     return out.astype(x.dtype)
 
@@ -116,14 +156,19 @@ def mlp_init(key, d_model, d_ff, dtype, gated=True):
     return p
 
 
-@jax.named_scope("mlp.ffn")
-def mlp_apply(params, x, act=jax.nn.silu):
+def mlp(params, x, act=jax.nn.silu):
+    """``mlp_apply`` outside its named scope, for callers that name it."""
     h = x @ params["w_in"]
     if "w_gate" in params:
         h = act(x @ params["w_gate"]) * h
     else:
         h = act(h)
     return h @ params["w_out"]
+
+
+@jax.named_scope("mlp.ffn")
+def mlp_apply(params, x, act=jax.nn.silu):
+    return mlp(params, x, act)
 
 
 def mlp_specs(rules, gated=True):

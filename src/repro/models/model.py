@@ -101,13 +101,9 @@ def forward_train(params, batch, cfg: ModelConfig, use_kernel: bool = True, rema
             aux += auxs.sum()
     x, aux2 = T.stack_train(params["stack"], x, cfg, positions, mrope, use_kernel, remat, unroll)
     aux += aux2
-    h = _norm_f(cfg)(params["final_norm"], x)
+    h = T.norm_fn(cfg)(params["final_norm"], x)
     logits = _unembed(params, h, cfg)
     return logits, aux, h
-
-
-def _norm_f(cfg):
-    return L.rmsnorm if cfg.norm == "rmsnorm" else L.layernorm
 
 
 @jax.named_scope("unembed.logits")
@@ -124,7 +120,7 @@ def mtp_logits(params, h, batch, cfg, use_kernel=True):
     B, S = tokens.shape
     nxt = jnp.pad(tokens[:, 1:], ((0, 0), (0, 1)))
     e = L.embed_apply(params["embed"], nxt).astype(h.dtype)
-    z = jnp.concatenate([_norm_f(cfg)(params["mtp"]["norm"], h), e], axis=-1)
+    z = jnp.concatenate([T.norm_fn(cfg)(params["mtp"]["norm"], h), e], axis=-1)
     z = z @ params["mtp"]["proj"]
     positions = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32), (B, S))
     z, _ = T.member_train(params["mtp"]["block"], z, cfg, "attn", "mlp", positions, None, use_kernel)
@@ -222,7 +218,7 @@ def decode_step(params, cache, batch, position, cfg: ModelConfig, unroll: bool =
         new_cache["prefix"] = (pc,)
     x, nsc = T.stack_decode(params["stack"], x, cache["stack"], cfg, position, mrope, unroll)
     new_cache["stack"] = nsc
-    h = _norm_f(cfg)(params["final_norm"], x)
+    h = T.norm_fn(cfg)(params["final_norm"], x)
     logits = _unembed(params, h, cfg)
     return logits[:, 0], new_cache
 
@@ -261,6 +257,6 @@ def decode_step_staged(params, cache, batch, position, cfg: ModelConfig):
         params["stack"], x, cache["stack"], cfg, position, mrope
     )
     new_cache["stack"] = nsc
-    h = _norm_f(cfg)(params["final_norm"], x)
+    h = T.norm_fn(cfg)(params["final_norm"], x)
     logits = _unembed(params, h, cfg)
     return logits[:, 0], new_cache

@@ -16,16 +16,19 @@ from repro.models import layers as L
 
 
 def moe_init(key, cfg, dtype):
+    """The router over all ``num_experts``; the weights of the experts this
+    chip holds (``MoEConfig.held_experts``); the shared experts as one MLP."""
     m = cfg.moe
     d = cfg.d_model
+    E = m.n_held
     ks = jax.random.split(key, 4)
     s_in = d ** -0.5
     s_out = m.d_ff_expert ** -0.5
     p = {
         "router": L.truncated_normal(ks[0], (d, m.num_experts), dtype, s_in),
-        "w_in": L.truncated_normal(ks[1], (m.num_experts, d, m.d_ff_expert), dtype, s_in),
-        "w_gate": L.truncated_normal(ks[2], (m.num_experts, d, m.d_ff_expert), dtype, s_in),
-        "w_out": L.truncated_normal(ks[3], (m.num_experts, m.d_ff_expert, d), dtype, s_out),
+        "w_in": L.truncated_normal(ks[1], (E, d, m.d_ff_expert), dtype, s_in),
+        "w_gate": L.truncated_normal(ks[2], (E, d, m.d_ff_expert), dtype, s_in),
+        "w_out": L.truncated_normal(ks[3], (E, m.d_ff_expert, d), dtype, s_out),
     }
     if m.shared_experts:
         p["shared"] = L.mlp_init(
@@ -35,7 +38,7 @@ def moe_init(key, cfg, dtype):
 
 
 def moe_specs(cfg, rules):
-    E = cfg.moe.num_experts
+    E = cfg.moe.n_held
     p = {
         "router": P(None, None),
         "w_in": rules.expert((E, 0, 0), ff_dim=2, n_experts=E),
@@ -74,6 +77,9 @@ def moe_apply(params, x, cfg):
     the dispatch/combine contractions into all-to-alls on that axis —
     the §3 collective in fused form. O(T·E) routing memory, exact top-k
     (no capacity drops) — the reference semantics for the EP fast path.
+    With ``held_experts`` set, routing is over all experts and only the
+    held experts' part of the result is computed (see
+    ``moe_apply_sparse``).
     """
     m = cfg.moe
     B, S, d = x.shape
@@ -82,9 +88,11 @@ def moe_apply(params, x, cfg):
     logits = xt @ params["router"]
     w, idx = router_topk(logits, m.top_k, m.norm_topk_probs)
     # combine[t, e] = sum_k w[t,k] * [idx[t,k] == e]
-    combine = jnp.zeros((T, m.num_experts), jnp.float32)
     onehot = jax.nn.one_hot(idx, m.num_experts, dtype=jnp.float32)  # (T, k, E)
     combine = (onehot * w[..., None]).sum(axis=1)  # (T, E)
+    if m.held_experts is not None:
+        first, n = m.held_experts
+        combine = combine[:, first:first + n]
     # dispatch: every expert sees all tokens weighted by membership.
     # grouped einsum keeps peak memory at (E, T, ff) tiles XLA can shard.
     h_in = jnp.einsum("td,edf->etf", xt, params["w_in"])
@@ -94,7 +102,8 @@ def moe_apply(params, x, cfg):
     y = jnp.einsum("etd,te->td", y_e.astype(jnp.float32), combine)
     y = y.astype(x.dtype)
     if "shared" in params:
-        y = y + L.mlp_apply(params["shared"], xt)
+        with jax.named_scope("moe.shared_ffn"):
+            y = y + L.mlp(params["shared"], xt)
     aux = load_balance_loss(logits, idx, m.num_experts, m.top_k)
     return y.reshape(B, S, d), aux
 
@@ -104,7 +113,17 @@ def moe_apply_sparse(params, x, cfg, capacity_factor: float | None = None):
     into per-expert buffers of size C = cf·T·k/E; overflow drops (standard
     Switch/Mixtral-style). This is the formulation whose dispatch IS an
     all-to-all over the EP axis — bound to dragonfly_all_to_all in the
-    shard_map training variant (train/step_dragonfly.py)."""
+    shard_map training variant (train/step_dragonfly.py).
+
+    Expert share: with ``held_experts`` = (first, n) the layer is one chip
+    of an expert-parallel deployment. It routes each token over all
+    ``num_experts`` with the full-width router, builds and runs buffers
+    for its n experts only, at the capacity the global count gives, and
+    returns their part of the result; assignments to experts held
+    elsewhere contribute nothing here. Shared experts, which every chip
+    computes alike, are added once. Summed over the shares of a
+    deployment (shared experts counted once) the parts give the uncut
+    layer."""
     from repro.dist import sharding as SH
 
     m = cfg.moe
@@ -133,7 +152,11 @@ def moe_apply_sparse(params, x, cfg, capacity_factor: float | None = None):
         pos_in_e = (jnp.cumsum(onehot, axis=0) - 1) * onehot  # (T*k, E)
         slot = pos_in_e.sum(-1)  # (T*k,)
         keep = slot < C
-        buf = jnp.zeros((E, C, d), xt.dtype)
+        if m.held_experts is not None:  # this chip's experts, locally indexed
+            first, n = m.held_experts
+            keep &= (flat_e >= first) & (flat_e < first + n)
+            flat_e = jnp.clip(flat_e - first, 0, n - 1)
+        buf = jnp.zeros((m.n_held, C, d), xt.dtype)
         src_tok = jnp.repeat(jnp.arange(T), m.top_k)
         buf = buf.at[flat_e, jnp.clip(slot, 0, C - 1)].add(
             jnp.where(keep[:, None], xt[src_tok], 0)
@@ -157,7 +180,8 @@ def moe_apply_sparse(params, x, cfg, capacity_factor: float | None = None):
         )
         y = y.astype(x.dtype)
     if "shared" in params:
-        y = y + L.mlp_apply(params["shared"], xt)
+        with jax.named_scope("moe.shared_ffn"):
+            y = y + L.mlp(params["shared"], xt)
     aux = load_balance_loss(logits, idx, E, m.top_k)
     return y.reshape(B, S, d), aux
 
@@ -173,8 +197,8 @@ def moe_apply_ep(params, x, cfg):
     doubly-parallel ppermute schedule: the §3 Schedule IR emitted by
     core/alltoall.py, lowered to a CollectiveProgram by
     runtime/lowering.py, replayed by the jax_ppermute backend (via
-    dist/collectives.py) — same payload, K·M²/s visible rounds (see
-    EXPERIMENTS.md §Perf). ``dragonfly_overlap`` replays the same program
+    dist/collectives.py) — same payload, K·M²/s visible rounds.
+    ``dragonfly_overlap`` replays the same program
     in start_step order so independent ppermutes overlap.
     ``dragonfly_overlap_fused`` goes further: dispatch, expert FFN and
     combine become ONE fused round trip (``dragonfly_all_to_all_compute``
@@ -332,7 +356,7 @@ def moe_apply_tp(params, x, cfg):
     tensor axis; dispatch is LOCAL (per data shard), the only collective
     is the per-layer psum of the d-dim partial outputs — no token
     all-gather (the pjit sparse path's scatter pulled the full global
-    token set to every chip; see EXPERIMENTS.md §Perf cell A, iter 1)."""
+    token set to every chip)."""
     from repro.dist import sharding as SH
     from jax.sharding import PartitionSpec as PS
 
@@ -395,11 +419,12 @@ def moe_apply_tp(params, x, cfg):
 
 def moe_apply_auto(params, x, cfg):
     """Pick the shard_map path matching the expert layout when a launcher
-    registered rules; otherwise the sparse pjit path (single device)."""
+    registered rules; otherwise the sparse pjit path (single device, or
+    one chip's expert share)."""
     from repro.dist import sharding as SH
 
     act = SH.active()
-    if act is not None:
+    if act is not None and cfg.moe.held_experts is None:
         rules = act[0]
         T = x.shape[0] * x.shape[1]
         if rules.expert_parallel(cfg.moe.num_experts):

@@ -59,8 +59,9 @@ def member_specs(cfg, rules, mixer: str, ffn: str):
     return s
 
 
-def _norm(cfg):
-    return L.rmsnorm if cfg.norm == "rmsnorm" else L.layernorm
+def norm_fn(cfg):
+    norm = L.rmsnorm if cfg.norm == "rmsnorm" else L.layernorm
+    return functools.partial(norm, eps=cfg.norm_eps)
 
 
 def member_train(params, x, cfg, mixer, ffn, positions, mrope_positions, use_kernel):
@@ -72,7 +73,7 @@ def member_train(params, x, cfg, mixer, ffn, positions, mrope_positions, use_ker
         # axis between blocks — XLA turns the TP all-reduces into
         # reduce-scatter + all-gather pairs (half the collective bytes).
         x = SH.constrain(x, act[0].batch_axes, act[0].tensor_axis, None)
-    norm = _norm(cfg)
+    norm = norm_fn(cfg)
     h = norm(params["norm1"], x)
     if mixer == "attn":
         if cfg.attention == "mla":
@@ -105,7 +106,7 @@ def member_decode_mixer(params, x, cache, layer, cfg, mixer, position, mrope_pos
     writes the new token's rows into layer ``layer`` and reads the layer
     where it lies; a recurrent mixer's new state replaces its layer's.
     Returns (x, cache) — the FFN half (if any) applies on top."""
-    norm = _norm(cfg)
+    norm = norm_fn(cfg)
     h = norm(params["norm1"], x)
     if mixer == "attn":
         if cfg.attention == "mla":
@@ -144,7 +145,7 @@ def member_decode(params, x, cache, layer, cfg, mixer, ffn, position, mrope_posi
         params, x, cache, layer, cfg, mixer, position, mrope_positions
     )
     if ffn != "none":
-        h2 = _norm(cfg)(params["norm2"], x)
+        h2 = norm_fn(cfg)(params["norm2"], x)
         if ffn == "moe":
             y, _ = MOE.moe_apply_auto(params["ffn"], h2, cfg)
         else:
@@ -281,7 +282,7 @@ def stack_decode_staged(stack_params, x, caches, cfg, position, mrope_positions=
     layer of the stacked caches, as in ``stack_decode``.
     """
     pattern = cfg.layer_kinds()
-    norm = _norm(cfg)
+    norm = norm_fn(cfg)
     caches = list(caches)
     for g in range(cfg.n_groups):
         group_params = jax.tree.map(lambda a: a[g], stack_params)
@@ -329,10 +330,7 @@ def stack_cache_specs(cfg, rules, long_context: bool):
     for mixer, _ in pattern:
         if mixer == "attn":
             if cfg.attention == "mla":
-                specs.append({
-                    "c_kv": P(None, b, t, None),     # (G, B, S, r)
-                    "k_rope": P(None, b, t, None),
-                })
+                specs.append({"latent": P(None, b, t, None)})  # (G, B, S, r + dr)
             else:
                 specs.append({
                     "k": P(None, b, t, None, None),  # (G, B, S, kvh, hd)

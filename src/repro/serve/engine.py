@@ -12,9 +12,9 @@ operations when one is being recorded and cost about a microsecond each
 when none is: ``engine.admit(rid, prompt_tokens)``, then per batched
 forward ``engine.step(kind, slots[, rid])`` holding ``engine.inputs``
 (host-to-device copies), ``engine.dispatch`` (the jitted call until it
-returns), ``engine.fetch`` (waiting for the logits and copying them to
-the host) and ``engine.commit``. ``Engine.stats`` counts the work;
-``Request`` carries its own timestamps.
+returns), ``engine.fetch`` (waiting for each slot's greedy token and
+copying it to the host) and ``engine.commit``. ``Engine.stats`` counts
+the work; ``Request`` carries its own timestamps.
 """
 
 from __future__ import annotations
@@ -45,6 +45,15 @@ class Request:
     first_token_at: float | None = None
 
 
+def greedy_step(params, cache, batch, positions, cfg):
+    """``decode_step`` returning each slot's greedy next token (the first
+    index of its largest logit) in place of the logits, in the same
+    program: the host fetches one id per slot, not (slots, vocab) logits
+    to search."""
+    logits, cache = M.decode_step(params, cache, batch, positions, cfg)
+    return jnp.argmax(logits, axis=-1), cache
+
+
 @dataclasses.dataclass
 class EngineStats:
     """What the engine has done since it started or since ``reset()``."""
@@ -72,7 +81,7 @@ class Engine:
         # it, and the previous ``self.cache`` is then deleted, so nothing
         # may hold it across a step
         self._step = jax.jit(
-            lambda p, c, b, pos: M.decode_step(p, c, b, pos, self.cfg),
+            lambda p, c, b, pos: greedy_step(p, c, b, pos, self.cfg),
             donate_argnums=(1,),
         )
         self._reset_states = None
@@ -141,20 +150,21 @@ class Engine:
         """One batched model forward over all slots (the seam subclasses
         override — ``serve.fleet.FleetEngine`` runs the staged decode here
         so MoE boundaries can be serviced by a combined host program).
-        Returns host logits (slots, vocab) and updates ``self.cache``."""
-        logits = self._dispatch()
+        Returns each slot's greedy next token on the host (slots,) and
+        updates ``self.cache``."""
+        tokens = self._dispatch()
         with TraceAnnotation("engine.fetch"):
-            return np.asarray(logits, np.float32)
+            return np.asarray(tokens)
 
     def _dispatch(self) -> jax.Array:
         """Copy the step's inputs to the device and launch the decode step;
-        returns its logits, still on the device."""
+        returns its greedy tokens, still on the device."""
         with TraceAnnotation("engine.inputs"):
             batch = {"token": jnp.asarray(self.pending_tok)}
             positions = jnp.asarray(self.positions)
         with TraceAnnotation("engine.dispatch"):
-            logits, self.cache = self._step(self.params, self.cache, batch, positions)
-        return logits
+            tokens, self.cache = self._step(self.params, self.cache, batch, positions)
+        return tokens
 
     def _advance(self, decode_slots):
         if self._prefill_rid is None:
@@ -162,13 +172,13 @@ class Engine:
         else:
             tags = {"kind": "prefill", "rid": self._prefill_rid}
         with TraceAnnotation("engine.step", slots=len(self.slot_req), **tags):
-            logits = self._forward()
-            return self._commit(logits, decode_slots)
+            tokens = self._forward()
+            return self._commit(tokens, decode_slots)
 
-    def _commit(self, logits, decode_slots):
-        """Book one forward's results: count it, bump positions,
-        argmax-append for the decoding slots, retire finished requests and
-        free their slots."""
+    def _commit(self, tokens, decode_slots):
+        """Book one forward's results: count it, bump positions, append
+        each decoding slot's greedy token (``tokens``, from ``_forward``),
+        retire finished requests and free their slots."""
         with TraceAnnotation("engine.commit"):
             active = list(self.slot_req)
             st = self.stats
@@ -181,7 +191,7 @@ class Engine:
             now = time.perf_counter()
             for slot in decode_slots:
                 req = self.slot_req[slot]
-                nxt = int(np.argmax(logits[slot]))
+                nxt = int(tokens[slot])
                 if not req.out:
                     req.first_token_at = now
                 req.out.append(nxt)
@@ -189,7 +199,7 @@ class Engine:
                 if len(req.out) >= req.max_new_tokens or self.positions[slot] >= self.max_seq - 1:
                     req.done = True
                     del self.slot_req[slot]
-        return logits
+        return tokens
 
     def step(self):
         """One decode step for every active slot (batched)."""

@@ -73,7 +73,7 @@ class FleetEngine(Engine):
         super().__init__(cfg, params, batch_slots, max_seq)
         self._service = service     # (ffn_params, h2) -> y
         self._gen = None
-        self._last_logits = None
+        self._last_tokens = None
 
     def begin_forward(self):
         """Start one staged forward over all slots; returns the first MoE
@@ -87,13 +87,13 @@ class FleetEngine(Engine):
     def pump(self, y):
         """Resume the staged forward with expert output ``y`` (None to
         start). Returns the next boundary's item, or None when the forward
-        finished — logits are then in ``_last_logits`` and the cache is
-        committed."""
+        finished — its greedy tokens are then in ``_last_tokens`` and the
+        cache is committed."""
         try:
             item = next(self._gen) if y is None else self._gen.send(y)
         except StopIteration as stop:
             logits, self.cache = stop.value
-            self._last_logits = np.asarray(logits, np.float32)
+            self._last_tokens = np.asarray(logits, np.float32).argmax(-1)
             self._gen = None
             return None
         return item
@@ -102,7 +102,7 @@ class FleetEngine(Engine):
         item = self.begin_forward()
         while item is not None:
             item = self.pump(jnp.asarray(self._service(*item)))
-        return self._last_logits
+        return self._last_tokens
 
 
 @dataclasses.dataclass
@@ -256,7 +256,7 @@ class TenantFleet:
                     nxt[tid] = it
             items = nxt
         for t in active.values():
-            t.engine._commit(t.engine._last_logits,
+            t.engine._commit(t.engine._last_tokens,
                              decode_slots=list(t.engine.slot_req))
         self.steps_run += 1
 

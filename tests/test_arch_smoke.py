@@ -117,6 +117,7 @@ def test_full_config_param_counts():
     expect = {
         "mixtral-8x7b": (40e9, 52e9),       # 8x7B total ~46.7B
         "deepseek-v3-671b": (600e9, 720e9),
+        "deepseek-v2-lite": (15.5e9, 16e9),  # 15.7B total
         "llama3-405b": (380e9, 430e9),
         "tinyllama-1.1b": (0.9e9, 1.3e9),
         "phi3-mini-3.8b": (3.3e9, 4.3e9),

@@ -3,8 +3,8 @@ the cache to its jitted step, each layer writes the new token's rows into
 the stacked cache where it lies (a recurrent mixer replaces its layer's
 state), and the step returns that buffer. Checked on the compiled step's
 text and against rows written into a copy on the host, for GQA (Mixtral),
-MLA (DeepSeek-V3, with its dense prefix), a GQA/Mamba hybrid (Jamba) and
-mLSTM/sLSTM (xLSTM)."""
+MLA's latent rows (DeepSeek-V3 and DeepSeek-V2-Lite, with their dense
+prefix), a GQA/Mamba hybrid (Jamba) and mLSTM/sLSTM (xLSTM)."""
 
 import jax
 import jax.numpy as jnp
@@ -17,8 +17,9 @@ from repro.models import model as M
 from repro.serve.engine import Engine, Request
 from test_serve_engine import greedy_reference
 
-ARCHS = ["mixtral-8x7b", "deepseek-v3-671b", "jamba-1.5-large-398b", "xlstm-1.3b"]
-SEQUENCE_LEAVES = ("k", "v", "c_kv", "k_rope")  # (L, B, max_seq, ...)
+ARCHS = ["mixtral-8x7b", "deepseek-v3-671b", "deepseek-v2-lite", "jamba-1.5-large-398b",
+         "xlstm-1.3b"]
+SEQUENCE_LEAVES = ("k", "v", "latent")  # (L, B, max_seq, ...)
 SLOTS, MAX_SEQ = 3, 40
 
 

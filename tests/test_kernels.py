@@ -147,3 +147,39 @@ def test_decode_attention_vs_ref(window, block):
     p = jax.nn.softmax(jnp.where(valid[:, None, None], s, -1e30), axis=-1)
     want = jnp.einsum("bhgk,bkhd->bhgd", p, vc[1], precision="highest").reshape(B, H, hd)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2 ** -8, rtol=0)
+
+
+@pytest.mark.parametrize("block", [512, 128])
+def test_mla_decode_attention_vs_jnp(block):
+    """The MLA decode kernel (interpret mode) against the jnp form a CPU
+    takes (``attention._mla_decode_xla``), over one layer of a stacked
+    latent cache of 2048 positions with DeepSeek-V2-Lite's row (512 + 64,
+    padded to 640): slots at position 0, at both sides of block edges and
+    at 2047. Each slot's row at its position is ``sign(q_0)`` (head 0's
+    query), which head 0 then attends to almost alone, and the row after it
+    ``2 sign(q_0)``, which it would attend to instead were the position
+    past the slot's read: a block read one short or one long moves head 0
+    by whole units. Inputs lie on the bf16 grid, so only the kernel's bf16
+    probabilities round: values |v| <= 2 bound the difference by 2**-7."""
+    from repro.kernels.flash_attention.mla_decode import mla_decode_attention
+    from repro.models.attention import _mla_decode_xla
+
+    rng = np.random.default_rng(5)
+    L, B, S, r, dr, w, H = 2, 6, 2048, 512, 64, 640, 16
+    grid = lambda *shape: jnp.asarray(rng.uniform(-1, 1, shape), jnp.bfloat16).astype(jnp.float32)
+    q = grid(B, H, w).at[..., r + dr:].set(0.0)
+    pos = np.asarray([0, 511, 512, 1023, 1024, 2047], np.int32)
+    latent = np.array(grid(L, B, S, w))
+    loud = np.sign(np.asarray(q[:, 0]))
+    for b, p in enumerate(pos):
+        latent[1, b, p] = loud[b]
+        if p + 1 < S:
+            latent[1, b, p + 1] = 2 * loud[b]
+    latent = jnp.asarray(latent).at[..., r + dr:].set(0.0)
+    scale = (r + dr) ** -0.5
+    pos = jnp.asarray(pos)
+    got = mla_decode_attention(q, latent, 1, pos, r=r, scale=scale, block=block,
+                               interpret=True)
+    want = _mla_decode_xla(q, latent[1], pos, r, scale)
+    np.testing.assert_allclose(np.asarray(want)[:, 0], loud[:, :r], atol=0.05)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2 ** -7, rtol=0)

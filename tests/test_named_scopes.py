@@ -1,7 +1,7 @@
 """Every named scope of the model step reaches the compiled program's
 ``op_name`` metadata: the decode step (MoE, cached attention, the layer
-scan) and the train step (Pallas flash forward with its XLA backward,
-loss, AdamW), compiled on the CPU at smoke size."""
+scan; MLA with shared experts) and the train step (Pallas flash forward
+with its XLA backward, loss, AdamW), compiled on the CPU at smoke size."""
 
 import re
 
@@ -61,3 +61,21 @@ def test_decode_step_scopes(decode_ops, scope):
     "mlp.ffn", "unembed.logits", "loss.xent", "optimizer.adamw"])
 def test_train_step_scopes(train_ops, scope):
     assert re.search(rf"(^|[/(]){re.escape(scope)}[/)]", train_ops, re.M), scope
+
+
+@pytest.fixture(scope="module")
+def mla_decode_ops():
+    """DeepSeek-V2-Lite's decode step: absorbed MLA, shared experts."""
+    cfg = get_smoke_config("deepseek-v2-lite")
+    params = M.init_params(jax.random.key(0), cfg)
+    cache = M.init_cache(cfg, 4, 64, dtype=jnp.float32)
+    step = jax.jit(lambda p, c, b, pos: M.decode_step(p, c, b, pos, cfg))
+    return _scopes(step.lower(params, cache, {"token": jnp.zeros(4, jnp.int32)},
+                              jnp.zeros(4, jnp.int32)).compile().as_text())
+
+
+@pytest.mark.parametrize("scope", [
+    "attn.qkv", "attn.absorb", "attn.kv_update", "attn.decode", "attn.out",
+    "moe.router", "moe.expert_ffn", "moe.shared_ffn", "mlp.ffn", "decode.layers"])
+def test_mla_decode_step_scopes(mla_decode_ops, scope):
+    assert re.search(rf"(^|[/(]){re.escape(scope)}[/)]", mla_decode_ops, re.M), scope

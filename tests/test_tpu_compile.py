@@ -179,3 +179,72 @@ def test_serve_decode_step_updates_cache_in_place(one_chip, monkeypatch):
     assert [m for m in made if m[2] not in allowed] == []
     assert any(kind == "fusion:scatter" for _, _, kind in made)
     assert 'custom_call_target="tpu_custom_call"' in text
+
+
+def test_mla_decode_attention_compiles(one_chip):
+    """The MLA decode kernel over the longgen cell's stacked f32 latent
+    cache (DeepSeek-V2-Lite: 27 layers, 32 slots of 2048 positions, rows
+    of 512 + 64 padded to 640, 16 heads): no copy and no scratch in HBM,
+    and the kernel's instruction is named for the trace."""
+    import re
+
+    from repro.kernels.flash_attention.mla_decode import mla_decode_attention
+
+    L, B, S, r, w, H = 27, 32, 2048, 512, 640, 16
+    latent = jax.ShapeDtypeStruct((L, B, S, w), jnp.float32, sharding=one_chip)
+    compiled = jax.jit(lambda q, c, layer, pos: mla_decode_attention(
+        q, c, layer, pos, r=r, scale=0.1, interpret=False)).lower(
+        jax.ShapeDtypeStruct((B, H, w), jnp.float32, sharding=one_chip), latent,
+        jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip),
+        jax.ShapeDtypeStruct((B,), jnp.int32, sharding=one_chip)).compile()
+    calls = [line for line in compiled.as_text().splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert calls and all(re.match(r"\s*(ROOT )?%mla_decode", c) for c in calls)
+    assert compiled.memory_analysis().temp_size_in_bytes == 0
+
+
+def test_mla_serve_decode_step_reads_the_latent_cache_in_place(one_chip, monkeypatch):
+    """The engine's decode step (cache donated) at the longgen cell's
+    shapes: DeepSeek-V2-Lite at its published widths, 27 layers (the dense
+    one and 26 of 8 held experts), 32 slots of 2048 f32 latent rows. Every
+    cache leaf shares its buffer with an output; outside fusions no
+    instruction makes an array of a layer's or of the stack's latent dims
+    but the in-place row update; no array anywhere has the (slots,
+    positions, heads) dims of keys or values expanded from the latent
+    rows; and the attention is the MLA kernel."""
+    import dataclasses
+    import functools
+    import re
+
+    from _hlo import aliased_parameter_dims, materialized
+    from repro.configs import get_config
+    from repro.kernels.flash_attention import mla_decode
+    from repro.models import attention
+    from repro.models import model as M
+
+    monkeypatch.setattr(attention, "default_impl", lambda: "pallas")
+    monkeypatch.setattr(attention, "mla_decode_attention",
+                        functools.partial(mla_decode.mla_decode_attention, interpret=False))
+    base = get_config("deepseek-v2-lite")
+    cfg = dataclasses.replace(base, moe=dataclasses.replace(base.moe, held_experts=(0, 8)))
+    B, S = 32, 2048
+    on = lambda tree: jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip), tree)
+    params = on(jax.eval_shape(lambda: M.init_params(jax.random.key(0), cfg)))
+    cache = on(jax.eval_shape(lambda: M.init_cache(cfg, B, S, dtype=jnp.float32)))
+    vec = jax.ShapeDtypeStruct((B,), jnp.int32, sharding=one_chip)
+    step = jax.jit(lambda p, c, b, pos: M.decode_step(p, c, b, pos, cfg),
+                   donate_argnums=(1,))  # as ``Engine`` jits it
+    text = step.lower(params, cache, {"token": vec}, vec).compile().as_text()
+
+    leaves = jax.tree.leaves(cache)
+    assert aliased_parameter_dims(text) == sorted(leaf.shape for leaf in leaves)
+    latent_dims = {d for leaf in leaves for d in (leaf.shape, leaf.shape[1:])}
+    made = [m for m in materialized(text) if m[1] in latent_dims]
+    allowed = {"parameter", "get-tuple-element", "bitcast", "fusion:scatter"}
+    assert [m for m in made if m[2] not in allowed] == []
+    H = cfg.n_heads
+    expanded = [d for d in re.findall(r"\[([\d,]+)\]", text)
+                if d.startswith(f"{B},{S},{H},") or d.startswith(f"{B},{H},{S},")]
+    assert expanded == []
+    assert re.search(r"%mla_decode\S* = .*tpu_custom_call", text)
