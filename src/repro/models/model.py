@@ -158,8 +158,13 @@ def loss_fn(params, batch, cfg: ModelConfig, use_kernel: bool = True, remat: boo
 
 # ---------------------------------------------------------------- decode
 def init_cache(cfg: ModelConfig, batch: int, max_seq: int, dtype=None):
+    """Every layer's decode state for ``batch`` slots of ``max_seq``
+    positions, and ``experts_run``: the held experts the MoE layers have
+    run since, summed over steps and layers (int32, wrapping; counted by
+    ``decode_step``, not by the staged decode)."""
     dt = dtype or jnp.dtype(cfg.compute_dtype)
-    cache = {"stack": T.stack_cache_init(cfg, batch, max_seq, dt)}
+    cache = {"stack": T.stack_cache_init(cfg, batch, max_seq, dt),
+             "experts_run": jnp.zeros((), jnp.int32)}
     if cfg.first_dense_layers:
         one = T.member_cache_init(cfg, "attn", batch, max_seq, dt)
         cache["prefix"] = (
@@ -181,7 +186,7 @@ def reset_states(cache, cfg: ModelConfig, slot):
 
 
 def cache_specs(cfg: ModelConfig, rules, long_context: bool):
-    s = {"stack": T.stack_cache_specs(cfg, rules, long_context)}
+    s = {"stack": T.stack_cache_specs(cfg, rules, long_context), "experts_run": P()}
     if cfg.first_dense_layers:
         s["prefix"] = (T.stack_cache_specs(cfg, rules, long_context)[0],)
     return s
@@ -190,8 +195,11 @@ def cache_specs(cfg: ModelConfig, rules, long_context: bool):
 def decode_step(params, cache, batch, position, cfg: ModelConfig, unroll: bool = False):
     """One token for the whole batch at ``position`` (scalar or (B,)).
 
-    batch: {'token': (B,)} or {'embed': (B, d)} (+ mrope positions).
-    Returns (logits (B, vocab), new_cache).
+    batch: {'token': (B,)} or {'embed': (B, d)} (+ mrope positions), and
+    optionally 'live' (B,) bool: the rows that hold a request, the only
+    ones the MoE layers route (all when absent).
+    Returns (logits (B, vocab), new_cache); the new cache's
+    ``experts_run`` adds the held experts this step's MoE layers ran.
     """
     if cfg.embeds_input and "embed" in batch:
         x = batch["embed"][:, None].astype(jnp.dtype(cfg.compute_dtype))
@@ -205,7 +213,8 @@ def decode_step(params, cache, batch, position, cfg: ModelConfig, unroll: bool =
         def pre_fn(carry, inputs):
             x, c = carry
             member, i = inputs
-            return T.member_decode(member, x, c, i, cfg, "attn", "mlp", position, mrope), None
+            x, c, _ = T.member_decode(member, x, c, i, cfg, "attn", "mlp", position, mrope)
+            return (x, c), None
         carry = (x, cache["prefix"][0])
         if unroll:
             for i in range(cfg.first_dense_layers):
@@ -216,8 +225,10 @@ def decode_step(params, cache, batch, position, cfg: ModelConfig, unroll: bool =
             )
         x, pc = carry
         new_cache["prefix"] = (pc,)
-    x, nsc = T.stack_decode(params["stack"], x, cache["stack"], cfg, position, mrope, unroll)
+    x, nsc, experts_run = T.stack_decode(params["stack"], x, cache["stack"], cfg, position,
+                                         mrope, unroll, batch.get("live"))
     new_cache["stack"] = nsc
+    new_cache["experts_run"] = cache["experts_run"] + experts_run
     h = T.norm_fn(cfg)(params["final_norm"], x)
     logits = _unembed(params, h, cfg)
     return logits[:, 0], new_cache
@@ -248,7 +259,7 @@ def decode_step_staged(params, cache, batch, position, cfg: ModelConfig):
     if cfg.first_dense_layers:
         pc = cache["prefix"][0]
         for i in range(cfg.first_dense_layers):
-            x, pc = T.member_decode(
+            x, pc, _ = T.member_decode(
                 jax.tree.map(lambda a: a[i], params["prefix"][0]), x, pc, i,
                 cfg, "attn", "mlp", position, mrope,
             )

@@ -12,7 +12,12 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
+from repro.kernels.flash_attention.ops import default_impl
+from repro.kernels.moe.expert_ffn import expert_ffn
 from repro.models import layers as L
+
+# the routed experts' weights, (E, ...) in a layer's params
+EXPERT_WEIGHTS = ("w_in", "w_gate", "w_out")
 
 
 def moe_init(key, cfg, dtype):
@@ -108,7 +113,7 @@ def moe_apply(params, x, cfg):
     return y.reshape(B, S, d), aux
 
 
-def moe_apply_sparse(params, x, cfg, capacity_factor: float | None = None):
+def moe_apply_sparse(params, x, cfg, capacity_factor: float | None = None, live=None):
     """Capacity-bounded sparse dispatch (production path): tokens gather
     into per-expert buffers of size C = cf·T·k/E; overflow drops (standard
     Switch/Mixtral-style). This is the formulation whose dispatch IS an
@@ -123,7 +128,42 @@ def moe_apply_sparse(params, x, cfg, capacity_factor: float | None = None):
     elsewhere contribute nothing here. Shared experts, which every chip
     computes alike, are added once. Summed over the shares of a
     deployment (shared experts counted once) the parts give the uncut
-    layer."""
+    layer.
+
+    ``live`` (B,) bool, optional: tokens of a batch row where it is False
+    take no capacity row and get nothing from the routed experts (the
+    serving engine's free slots); None routes every token."""
+    y, aux, _ = _sparse(params, x, cfg, capacity_factor, live)
+    return y, aux
+
+
+def moe_decode(params, x, cfg, live=None, layer=None):
+    """The MoE layer of a decode step: ``moe_apply_auto``'s output for
+    ``x`` (B, 1, d), with the tokens of rows where ``live`` is False
+    masked out of routing as in ``moe_apply_sparse``, and the number of
+    held experts that ran (int32; a shard_map path runs them all).
+
+    With ``layer`` given, ``params``' expert weights are every layer's,
+    stacked ``(L, E, ...)`` (the decode loop keeps them out of its
+    per-layer slices), and layer ``layer``'s are used. Then on a TPU,
+    where the buffers are decode-sized (capacity at most 128 rows, bound
+    by the weights' bytes), the experts' FFN is the Pallas kernel
+    ``expert_ffn``, which reads only the weights of the experts some kept
+    row reaches, in place in the stacks; elsewhere the three einsums run
+    over every held expert."""
+    path = _sharded_path(cfg, x)
+    if path is not None:
+        if layer is not None:
+            params = dict(params, **{k: params[k][layer] for k in EXPERT_WEIGHTS})
+        return path(params, x, cfg)[0], jnp.int32(cfg.moe.n_held)
+    y, _, n_run = _sparse(params, x, cfg, None, live, layer)
+    return y, n_run
+
+
+def _sparse(params, x, cfg, capacity_factor, live, layer=None):
+    """``moe_apply_sparse``, and ``moe_decode``'s with stacked weights
+    (``layer``): (y, aux, the number of held experts some kept row
+    reaches)."""
     from repro.dist import sharding as SH
 
     m = cfg.moe
@@ -147,11 +187,17 @@ def moe_apply_sparse(params, x, cfg, capacity_factor: float | None = None):
         w, idx = router_topk(logits, m.top_k, m.norm_topk_probs)  # (T,k)
     with jax.named_scope("moe.dispatch"):
         flat_e = idx.reshape(-1)  # (T*k,)
-        # position of each (t, k) within its expert's buffer
+        # position of each (t, k) within its expert's buffer; a dead
+        # token's assignments take none
         onehot = jax.nn.one_hot(flat_e, E, dtype=jnp.int32)  # (T*k, E)
+        if live is not None:
+            live_k = jnp.repeat(live, S * m.top_k)  # (T*k,)
+            onehot = onehot * live_k[:, None]
         pos_in_e = (jnp.cumsum(onehot, axis=0) - 1) * onehot  # (T*k, E)
         slot = pos_in_e.sum(-1)  # (T*k,)
         keep = slot < C
+        if live is not None:
+            keep &= live_k
         if m.held_experts is not None:  # this chip's experts, locally indexed
             first, n = m.held_experts
             keep &= (flat_e >= first) & (flat_e < first + n)
@@ -161,15 +207,25 @@ def moe_apply_sparse(params, x, cfg, capacity_factor: float | None = None):
         buf = buf.at[flat_e, jnp.clip(slot, 0, C - 1)].add(
             jnp.where(keep[:, None], xt[src_tok], 0)
         )
+        # the held experts some kept row reaches
+        reached = jnp.zeros((m.n_held,), jnp.int32).at[flat_e].add(keep.astype(jnp.int32)) > 0
+        n_run = reached.sum(dtype=jnp.int32)
         if act:  # the §3 all-to-all boundary: tokens -> expert-major buffers
             buf = SH.constrain(buf, t_ax if ep else None, b_ax, None)
     with jax.named_scope("moe.expert_ffn"):
-        h = jax.nn.silu(jnp.einsum("ecd,edf->ecf", buf, params["w_gate"])) * jnp.einsum(
-            "ecd,edf->ecf", buf, params["w_in"]
-        )
-        if act:
-            h = SH.constrain(h, t_ax if ep else None, b_ax, None if ep else t_ax)
-        y_buf = jnp.einsum("ecf,efd->ecd", h, params["w_out"])  # (E, C, d)
+        w_gate, w_in, w_out = (params[k] for k in ("w_gate", "w_in", "w_out"))
+        if layer is not None and C <= 128 and not act and default_impl() == "pallas":
+            order = jnp.argsort(~reached, stable=True).astype(jnp.int32)  # reached first
+            y_buf = expert_ffn(buf, w_gate, w_in, w_out, layer, order, n_run)
+        else:
+            if layer is not None:
+                w_gate, w_in, w_out = w_gate[layer], w_in[layer], w_out[layer]
+            h = jax.nn.silu(jnp.einsum("ecd,edf->ecf", buf, w_gate)) * jnp.einsum(
+                "ecd,edf->ecf", buf, w_in
+            )
+            if act:
+                h = SH.constrain(h, t_ax if ep else None, b_ax, None if ep else t_ax)
+            y_buf = jnp.einsum("ecf,efd->ecd", h, w_out)  # (E, C, d)
     with jax.named_scope("moe.combine"):
         if act:  # combine all-to-all boundary
             y_buf = SH.constrain(y_buf, t_ax if ep else None, b_ax, None)
@@ -183,7 +239,7 @@ def moe_apply_sparse(params, x, cfg, capacity_factor: float | None = None):
         with jax.named_scope("moe.shared_ffn"):
             y = y + L.mlp(params["shared"], xt)
     aux = load_balance_loss(logits, idx, E, m.top_k)
-    return y.reshape(B, S, d), aux
+    return y.reshape(B, S, d), aux, n_run
 
 
 def moe_apply_ep(params, x, cfg):
@@ -417,22 +473,29 @@ def moe_apply_tp(params, x, cfg):
     return y.reshape(B, S, d), aux
 
 
+def _sharded_path(cfg, x):
+    """The shard_map path matching the expert layout when a launcher
+    registered rules and the tokens divide over its axes; else None."""
+    from repro.dist import sharding as SH
+
+    act = SH.active()
+    if act is None or cfg.moe.held_experts is not None:
+        return None
+    rules = act[0]
+    T = x.shape[0] * x.shape[1]
+    if rules.expert_parallel(cfg.moe.num_experts):
+        if T % (rules.model_axis_size * rules.data_axis_size) == 0:
+            return moe_apply_ep
+    elif T % rules.data_axis_size == 0:
+        return moe_apply_tp
+    return None
+
+
 def moe_apply_auto(params, x, cfg):
     """Pick the shard_map path matching the expert layout when a launcher
     registered rules; otherwise the sparse pjit path (single device, or
     one chip's expert share)."""
-    from repro.dist import sharding as SH
-
-    act = SH.active()
-    if act is not None and cfg.moe.held_experts is None:
-        rules = act[0]
-        T = x.shape[0] * x.shape[1]
-        if rules.expert_parallel(cfg.moe.num_experts):
-            if T % (rules.model_axis_size * rules.data_axis_size) == 0:
-                return moe_apply_ep(params, x, cfg)
-        elif T % rules.data_axis_size == 0:
-            return moe_apply_tp(params, x, cfg)
-    return moe_apply_sparse(params, x, cfg)
+    return (_sharded_path(cfg, x) or moe_apply_sparse)(params, x, cfg)
 
 
 def load_balance_loss(logits, idx, E, k):

@@ -140,20 +140,27 @@ def mixer_decode_jit(cfg, mixer):
     return jax.jit(fn)
 
 
-def member_decode(params, x, cache, layer, cfg, mixer, ffn, position, mrope_positions):
+def member_decode(params, x, cache, layer, cfg, mixer, ffn, position, mrope_positions,
+                  live=None):
+    """One decode member: the mixer, then the FFN. An MoE member's expert
+    weights are every layer's, stacked (``stack_decode`` keeps them out of
+    the layer loop's slices), and its routing skips the tokens of rows
+    where ``live`` is False (``moe.moe_decode``). Returns (x, cache, the
+    held experts run: 0 but for an MoE member)."""
     x, cache = member_decode_mixer(
         params, x, cache, layer, cfg, mixer, position, mrope_positions
     )
+    n_run = jnp.int32(0)
     if ffn != "none":
         h2 = norm_fn(cfg)(params["norm2"], x)
         if ffn == "moe":
-            y, _ = MOE.moe_apply_auto(params["ffn"], h2, cfg)
+            y, n_run = MOE.moe_decode(params["ffn"], h2, cfg, live, layer)
         else:
             y = L.mlp_apply(
                 params["ffn"], h2, act=jax.nn.silu if cfg.mlp_gated else jax.nn.gelu
             )
         x = x + y
-    return x, cache
+    return x, cache, n_run
 
 
 def member_cache_init(cfg, mixer, batch, max_seq, dtype):
@@ -233,35 +240,47 @@ def stack_train(stack_params, x, cfg, positions, mrope_positions=None, use_kerne
 
 
 def stack_decode(stack_params, x, caches, cfg, position, mrope_positions=None,
-                 unroll: bool = False):
+                 unroll: bool = False, live=None):
     """One token through the stack. The stacked ``caches`` ride in the layer
     loop's carry: each layer writes its update into its layer of them and
     reads that layer where it lies, so a donated cache is updated in place.
-    Returns (x, new_caches)."""
+    The MoE members' expert weights stay whole, outside the loop's
+    per-layer slices, for the expert kernel to read in place; ``live``
+    (B,) masks free slots out of their routing. Returns (x, new_caches,
+    the held experts run, summed over the MoE layers)."""
     pattern = cfg.layer_kinds()
+    experts, sliced = [], []  # per member: expert weights whole; the rest, sliced
+    for p, (_, ffn) in zip(stack_params, pattern):
+        whole = MOE.EXPERT_WEIGHTS if ffn == "moe" else ()
+        experts.append({k: p["ffn"][k] for k in whole})
+        if whole:
+            p = dict(p, ffn={k: a for k, a in p["ffn"].items() if k not in whole})
+        sliced.append(p)
+    sliced = tuple(sliced)
 
     def group_fn(carry, inputs):
-        x, caches = carry
+        x, caches, n_run = carry
         group_params, g = inputs
         caches = list(caches)
         for mi, (mixer, ffn) in enumerate(pattern):
-            x, caches[mi] = member_decode(
-                group_params[mi], x, caches[mi], g, cfg, mixer, ffn, position, mrope_positions
+            p = group_params[mi]
+            if experts[mi]:
+                p = dict(p, ffn=dict(p["ffn"], **experts[mi]))
+            x, caches[mi], n = member_decode(
+                p, x, caches[mi], g, cfg, mixer, ffn, position, mrope_positions, live
             )
-        return (x, tuple(caches)), None
+            n_run = n_run + n
+        return (x, tuple(caches), n_run), None
 
+    carry = (x, tuple(caches), jnp.int32(0))
     # the loop and its carry of the stacked caches
     with jax.named_scope("decode.layers"):
         if unroll:
             for g in range(cfg.n_groups):
-                (x, caches), _ = group_fn(
-                    (x, caches), (jax.tree.map(lambda a: a[g], stack_params), g)
-                )
+                carry, _ = group_fn(carry, (jax.tree.map(lambda a: a[g], sliced), g))
         else:
-            (x, caches), _ = jax.lax.scan(
-                group_fn, (x, tuple(caches)), (stack_params, jnp.arange(cfg.n_groups))
-            )
-    return x, caches
+            carry, _ = jax.lax.scan(group_fn, carry, (sliced, jnp.arange(cfg.n_groups)))
+    return carry
 
 
 def stack_decode_staged(stack_params, x, caches, cfg, position, mrope_positions=None):

@@ -62,6 +62,7 @@ class EngineStats:
     tokens_processed: int = 0  # occupied slots, summed over steps
     decode_tokens: int = 0     # tokens committed to requests
     context: int = 0           # positions in use (position + 1), summed
+    experts_run: int = 0       # held experts run, summed over steps and MoE layers
 
     def reset(self):
         self.__init__()
@@ -90,6 +91,7 @@ class Engine:
                 lambda c, slot: M.reset_states(c, self.cfg, slot), donate_argnums=(0,)
             )
         self.stats = EngineStats()
+        self._experts_total = 0  # the cache's ``experts_run`` last read
         self._prefill_rid = None  # the request ``admit`` is prefilling
 
     @property
@@ -154,13 +156,21 @@ class Engine:
         updates ``self.cache``."""
         tokens = self._dispatch()
         with TraceAnnotation("engine.fetch"):
-            return np.asarray(tokens)
+            # the ids and the cache's running count of experts run, together
+            tokens, total = jax.device_get((tokens, self.cache["experts_run"]))
+        self.stats.experts_run += (int(total) - self._experts_total) % 2 ** 32
+        self._experts_total = int(total)
+        return tokens
 
     def _dispatch(self) -> jax.Array:
         """Copy the step's inputs to the device and launch the decode step;
-        returns its greedy tokens, still on the device."""
+        returns its greedy tokens, still on the device. Only the occupied
+        slots, the one being prefilled among them, are live: the MoE
+        layers route no free slot's stale token."""
         with TraceAnnotation("engine.inputs"):
-            batch = {"token": jnp.asarray(self.pending_tok)}
+            live = np.zeros(self.slots, bool)
+            live[list(self.slot_req)] = True
+            batch = {"token": jnp.asarray(self.pending_tok), "live": jnp.asarray(live)}
             positions = jnp.asarray(self.positions)
         with TraceAnnotation("engine.dispatch"):
             tokens, self.cache = self._step(self.params, self.cache, batch, positions)

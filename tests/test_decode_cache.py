@@ -83,18 +83,18 @@ def _at(tree, path):
 
 def test_cache_matches_rows_written_into_a_copy(arch):
     """Serve six requests through two slots (admit, prefill, decode,
-    retire, a slot seated again), recording each step's inputs; replay
-    the steps through an un-donated jit of the same step, writing each
-    step's rows into a host copy. The engine's donated cache equals the
-    copy leaf for leaf, and every request's greedy tokens equal those it
-    gets served alone."""
+    retire, a slot seated again), recording each step's inputs (tokens,
+    live slots, positions); replay the steps through an un-donated jit of
+    the same step, writing each step's rows into a host copy. The
+    engine's donated cache equals the copy leaf for leaf, and every
+    request's greedy tokens equal those it gets served alone."""
     cfg, params = arch
     eng = Engine(cfg, params, batch_slots=2, max_seq=64)
     events, step, reset = [], eng._step, eng._reset_states
 
     def recording_step(p, c, b, pos):
         # copies: the engine reuses its host buffers
-        events.append((np.array(b["token"]), np.array(pos)))
+        events.append(({k: np.array(v) for k, v in b.items()}, np.array(pos)))
         return step(p, c, b, pos)
 
     def recording_reset(c, slot):
@@ -120,8 +120,8 @@ def test_cache_matches_rows_written_into_a_copy(arch):
     ref = jax.tree.map(lambda a: np.array(a), cache)
     for event in events:
         if isinstance(event, tuple):
-            tok, pos = event
-            _, new = plain(params, cache, {"token": jnp.asarray(tok)}, jnp.asarray(pos))
+            batch, pos = event
+            _, new = plain(params, cache, batch, jnp.asarray(pos))
         else:  # a request seated in a used slot: its recurrent state zeroed
             new, pos = M.reset_states(cache, cfg, event), None
         _write_rows(ref, new, cache, pos)
@@ -158,3 +158,122 @@ def test_decode_step_on_the_kernel_matches_the_jnp_path(arch, monkeypatch):
     want = logits("xla")
     np.testing.assert_allclose(logits("pallas"), want, rtol=0,
                                atol=4 * 2 ** -8 * np.abs(want).max())
+
+
+# ------------------------------------------------- live slots and experts
+# the MoE smoke configurations, DeepSeek-V2-Lite also as one chip's share
+# (experts 2 and 3 of 8, as the benchmark's DeepSeek cell holds 8 of 64)
+MOE_ARCHS = {"mixtral-8x7b": None, "deepseek-v2-lite": None,
+             "deepseek-v2-lite-share": (2, 2)}
+
+
+@pytest.fixture(scope="module", params=sorted(MOE_ARCHS))
+def moe_arch(request):
+    import dataclasses
+
+    cfg = get_smoke_config(request.param.removesuffix("-share"))
+    cfg = dataclasses.replace(cfg, moe=dataclasses.replace(
+        cfg.moe, held_experts=MOE_ARCHS[request.param]))
+    return cfg, M.init_params(jax.random.key(0), cfg)
+
+
+def _serve_six(eng, cfg):
+    """Six requests through two slots: admit, prefill, decode, retire,
+    slots seated again, and steps with a free slot."""
+    rng = np.random.default_rng(7)
+    reqs = [Request(rid=i, prompt=rng.integers(1, cfg.vocab, size=n).astype(np.int32),
+                    max_new_tokens=m)
+            for i, (n, m) in enumerate([(3, 5), (5, 2), (2, 4), (4, 3), (3, 3), (2, 2)])]
+    pending = list(reqs)
+    while pending or eng.slot_req:
+        while pending and eng.free_slots:
+            eng.admit(pending.pop(0))
+        eng.step()
+    return reqs
+
+
+def test_greedy_tokens_with_and_without_the_live_mask(moe_arch):
+    """Free slots masked out of routing or routed like live ones, every
+    request's greedy tokens are those it gets served alone; masked, the
+    step runs fewer experts."""
+    cfg, params = moe_arch
+    runs = {}
+    for masked in (True, False):
+        eng = Engine(cfg, params, batch_slots=2, max_seq=64)
+        if not masked:
+            step = eng._step
+            eng._step = lambda p, c, b, pos: step(p, c, {"token": b["token"]}, pos)
+        runs[masked] = eng.stats, _serve_six(eng, cfg)
+    for stats, reqs in runs.values():
+        for r in reqs:
+            assert r.out == greedy_reference(cfg, params, r.prompt, r.max_new_tokens), r.rid
+    assert runs[True][0].steps == runs[False][0].steps
+    assert 0 < runs[True][0].experts_run < runs[False][0].experts_run
+
+
+def test_experts_run_counts_the_held_experts_live_slots_reach(moe_arch, monkeypatch):
+    """``EngineStats.experts_run`` equals a host recount over the served
+    run: per step and MoE layer, the held experts that the router's top-k
+    of the live slots reaches (the router's choices taken out of the
+    engine's own step program)."""
+    from repro.models import moe as MOE
+
+    cfg, params = moe_arch
+    choices, lives = [], []
+    router_topk = MOE.router_topk
+
+    def recording_topk(logits, k, norm_probs):
+        w, idx = router_topk(logits, k, norm_probs)
+        jax.debug.callback(lambda i: choices.append(np.array(i)), idx, ordered=True)
+        return w, idx
+
+    monkeypatch.setattr(MOE, "router_topk", recording_topk)
+    eng = Engine(cfg, params, batch_slots=2, max_seq=64)
+    step = eng._step
+
+    def recording_step(p, c, b, pos):
+        lives.append(np.array(b["live"]))
+        return step(p, c, b, pos)
+
+    eng._step = recording_step
+    _serve_six(eng, cfg)
+    jax.effects_barrier()
+    moe_layers = sum(ffn == "moe" for _, ffn in cfg.layer_kinds()) * cfg.n_groups
+    assert len(choices) == moe_layers * len(lives) == moe_layers * eng.stats.steps
+    first, n = cfg.moe.held_experts or (0, cfg.moe.num_experts)
+    want = sum(len({int(e) for e in idx[live].ravel() if first <= e < first + n})
+               for idx, live in zip(choices, np.repeat(lives, moe_layers, axis=0)))
+    assert eng.stats.experts_run == want > 0
+
+
+def test_decode_step_on_the_expert_kernel_matches_the_jnp_path(moe_arch, monkeypatch):
+    """The decode step as a TPU runs its MoE layers (the expert-FFN
+    kernel over the stacked weights, here interpreted) gives the jnp
+    path's logits and expert count over ten steps with slots going live
+    and free. The configurations are float32, which both paths multiply
+    in, so the logits agree to float32 rounding."""
+    import functools
+
+    from repro.kernels.moe import expert_ffn
+    from repro.models import moe as MOE
+
+    cfg, params = moe_arch
+
+    def run(impl):
+        monkeypatch.setattr(MOE, "default_impl", lambda: impl)
+        monkeypatch.setattr(MOE, "expert_ffn",
+                            functools.partial(expert_ffn.expert_ffn, interpret=True))
+        step = jax.jit(lambda p, c, b, pos: M.decode_step(p, c, b, pos, cfg))
+        cache = M.init_cache(cfg, SLOTS, MAX_SEQ, dtype=jnp.float32)
+        rng, out = np.random.default_rng(1), []
+        for t in range(10):
+            batch = {"token": jnp.asarray(rng.integers(1, cfg.vocab, SLOTS), jnp.int32),
+                     "live": jnp.asarray([True, t % 3 > 0, t >= 4])}
+            lg, cache = step(params, cache, batch, jnp.asarray([t, t // 2, 3], jnp.int32))
+            out.append(np.asarray(lg))
+        return np.stack(out), int(cache["experts_run"])
+
+    want, n_want = run("xla")
+    got, n_got = run("pallas")
+    assert n_got == n_want > 0
+    np.testing.assert_allclose(got, want, rtol=0, atol=1e-5 * np.abs(want).max())

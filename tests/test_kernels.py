@@ -183,3 +183,62 @@ def test_mla_decode_attention_vs_jnp(block):
     want = _mla_decode_xla(q, latent[1], pos, r, scale)
     np.testing.assert_allclose(np.asarray(want)[:, 0], loud[:, :r], atol=0.05)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2 ** -7, rtol=0)
+
+
+# ------------------------------------------------------------- expert FFN
+# (L, E, C, d, f, tile, layer, experts run in order): every expert,
+# some, one; scaled-down Mixtral (f = 3.5 d, several d_ff tiles) and
+# DeepSeek-V2 (f = 0.69 d, one tile: the whole expert); layer 0 and not
+EXPERT_FFN_CASES = {
+    "all": (3, 4, 16, 128, 256, 128, 1, [0, 1, 2, 3]),
+    "some_empty": (3, 4, 16, 128, 256, 128, 2, [3, 1]),
+    "one": (2, 4, 16, 128, 256, 128, 0, [2]),
+    "mixtral_like": (2, 8, 16, 256, 896, 128, 1, [6, 0, 3]),
+    "deepseek_like": (3, 8, 16, 384, 256, None, 2, [1, 2, 4, 5, 7]),
+}
+
+
+@pytest.mark.parametrize("case", sorted(EXPERT_FFN_CASES))
+def test_expert_ffn_vs_einsums(case):
+    """The expert-FFN kernel (interpret mode) gives the three einsums'
+    result for each expert it is told to run, reading layer ``layer`` of
+    the stacked weights. Inputs lie on the bf16 grid and are bf16, as a
+    served step's; both sides round the two projections to bf16, so they
+    differ by a few bf16 ulps of the largest output. The weights of the
+    experts not run, and of the other layers, are NaN: a block read from
+    the wrong expert, tile or layer poisons what it touches."""
+    from repro.kernels.moe.expert_ffn import expert_ffn
+
+    L, E, C, d, f, tile, layer, run = EXPERT_FFN_CASES[case]
+    rng = np.random.default_rng(11)
+    grid = lambda *shape, s=1.0: jnp.asarray(rng.uniform(-s, s, shape), jnp.bfloat16)
+    x = grid(E, C, d)
+    w_gate, w_in = grid(L, E, d, f, s=d ** -0.5 * 2), grid(L, E, d, f, s=d ** -0.5 * 2)
+    w_out = grid(L, E, f, d, s=f ** -0.5 * 2)
+    unread = np.ones((L, E), bool)
+    unread[layer, run] = False
+    poison = lambda w: jnp.where(jnp.asarray(unread)[:, :, None, None], jnp.nan, w)
+    order = jnp.asarray(run + [e for e in range(E) if e not in run], jnp.int32)
+    got = expert_ffn(x, poison(w_gate), poison(w_in), poison(w_out), layer, order, len(run),
+                     tile=tile, interpret=True)
+    assert got.dtype == jnp.bfloat16 and got.shape == (E, C, d)
+
+    h = jax.nn.silu(jnp.einsum("ecd,edf->ecf", x, w_gate[layer])) * jnp.einsum(
+        "ecd,edf->ecf", x, w_in[layer])
+    want = np.asarray(jnp.einsum("ecf,efd->ecd", h, w_out[layer]), np.float32)[run]
+    got = np.asarray(got, np.float32)[run]
+    np.testing.assert_allclose(got, want, rtol=0, atol=4 * 2 ** -8 * np.abs(want).max())
+
+
+def test_expert_ffn_tile_fits_the_budget():
+    """Mixtral's experts stream in d_ff tiles of 512 (24 MiB of weight
+    blocks, double-buffered), DeepSeek-V2's whole (1408, 33 MiB); a d_ff
+    that is no multiple of 128 is one tile."""
+    from repro.kernels.moe.expert_ffn import VMEM_BUDGET, ffn_tile
+
+    assert ffn_tile(4096, 14336, 2) == 512
+    assert ffn_tile(2048, 1408, 2) == 1408
+    assert ffn_tile(64, 96, 4) == 96
+    for d, f in [(4096, 14336), (2048, 1408)]:
+        t = ffn_tile(d, f, 2)
+        assert 6 * d * t * 2 <= VMEM_BUDGET and f % t == 0

@@ -79,3 +79,53 @@ def test_shared_expert_always_active():
     p2 = dict(params, shared=jax.tree.map(jnp.zeros_like, params["shared"]))
     y2, _ = MOE.moe_apply_sparse(p2, x, cfg)
     assert not np.allclose(np.asarray(y), np.asarray(y2))
+
+
+# ------------------------------------------------------------- live mask
+LIVE = np.array([True, False, True, True, False, False])
+
+
+def test_live_mask_keeps_live_rows_and_zeroes_dead_ones():
+    """Masked out, a batch row gets nothing from the experts; the live
+    rows get what they get unmasked (capacity binds neither call)."""
+    cfg, params, x = _setup(seed=3, B=6, S=1)
+    y, _ = MOE.moe_apply_sparse(params, x, cfg)
+    y_live, _ = MOE.moe_apply_sparse(params, x, cfg, live=jnp.asarray(LIVE))
+    y, y_live = np.asarray(y), np.asarray(y_live)
+    np.testing.assert_allclose(y_live[LIVE], y[LIVE], rtol=1e-6, atol=1e-7)
+    assert (y_live[~LIVE] == 0).all() and (y[~LIVE] != 0).any()
+
+
+def test_dead_rows_take_no_capacity():
+    """40 tokens over 4 experts at capacity 16: unmasked, the 32 dead rows
+    ahead of the 8 live ones fill the buffers and live assignments drop;
+    masked, the live rows take every row they need and equal the
+    drop-free dense dispatch."""
+    cfg, params, x = _setup(seed=4, B=40, S=1)
+    live = np.arange(40) >= 32
+    oracle = np.asarray(MOE.moe_apply(params, x[live], cfg)[0])
+    masked = np.asarray(MOE.moe_apply_sparse(params, x, cfg, capacity_factor=0.5,
+                                             live=jnp.asarray(live))[0])[live]
+    unmasked = np.asarray(MOE.moe_apply_sparse(params, x, cfg, capacity_factor=0.5)[0])[live]
+    np.testing.assert_allclose(masked, oracle, rtol=2e-4, atol=2e-5)
+    assert not np.allclose(unmasked, oracle, rtol=2e-4, atol=2e-5)
+
+
+@pytest.mark.parametrize("held", [None, (2, 2)])
+def test_moe_decode_counts_the_held_experts_live_rows_reach(held):
+    """``moe_decode`` runs the held experts that some live row's top-k
+    reaches: its count equals that set's size, recounted on the host from
+    the router's choices."""
+    import dataclasses
+
+    cfg = get_smoke_config("deepseek-v2-lite")
+    cfg = dataclasses.replace(cfg, moe=dataclasses.replace(cfg.moe, held_experts=held))
+    params = MOE.moe_init(jax.random.key(5), cfg, jnp.float32)
+    x = jnp.asarray(np.random.default_rng(6).standard_normal((6, 1, cfg.d_model)),
+                    jnp.float32)
+    _, n_run = MOE.moe_decode(params, x, cfg, jnp.asarray(LIVE))
+    _, idx = MOE.router_topk(x[:, 0] @ params["router"], cfg.moe.top_k,
+                             cfg.moe.norm_topk_probs)
+    first, n = held or (0, cfg.moe.num_experts)
+    reached = {int(e) for e in np.asarray(idx)[LIVE].ravel() if first <= e < first + n}
+    assert int(n_run) == len(reached) < n

@@ -11,6 +11,7 @@ read back.
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -139,13 +140,58 @@ def test_decode_attention_compiles(one_chip):
     assert compiled.memory_analysis().temp_size_in_bytes == 0
 
 
+def _steer_to_expert_kernel(monkeypatch):
+    """The MoE path a TPU takes (the backend here is the CPU)."""
+    import functools
+
+    from repro.kernels.moe import expert_ffn
+    from repro.models import moe
+
+    monkeypatch.setattr(moe, "default_impl", lambda: "pallas")
+    monkeypatch.setattr(moe, "expert_ffn",
+                        functools.partial(expert_ffn.expert_ffn, interpret=False))
+
+
+def _engine_step_text(cfg, B, S, one_chip) -> str:
+    """The engine's decode step (cache donated, free slots masked) for
+    ``B`` slots of ``S`` f32 positions, compiled as ``Engine`` jits it."""
+    from repro.models import model as M
+    from repro.serve.engine import greedy_step
+
+    on = lambda tree: jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip), tree)
+    params = on(jax.eval_shape(lambda: M.init_params(jax.random.key(0), cfg)))
+    cache = on(jax.eval_shape(lambda: M.init_cache(cfg, B, S, dtype=jnp.float32)))
+    vec = jax.ShapeDtypeStruct((B,), jnp.int32, sharding=one_chip)
+    live = jax.ShapeDtypeStruct((B,), jnp.bool_, sharding=one_chip)
+    step = jax.jit(lambda p, c, b, pos: greedy_step(p, c, b, pos, cfg), donate_argnums=(1,))
+    return step.lower(params, cache, {"token": vec, "live": live}, vec).compile().as_text()
+
+
+def _expert_weights_made(text, cfg) -> list:
+    """Arrays of an expert-weight shape (a layer's or the stack's, either
+    orientation) that an instruction outside fusions makes, but the
+    parameters and the loop's pass-through of them."""
+    from _hlo import materialized
+
+    m = cfg.moe
+    size = lambda dims: tuple(sorted(d for d in dims if d != 1))
+    weights = {size(s) for s in [(cfg.d_model, m.d_ff_expert),
+                                 (m.n_held, cfg.d_model, m.d_ff_expert),
+                                 (cfg.n_groups, m.n_held, cfg.d_model, m.d_ff_expert)]}
+    allowed = {"parameter", "get-tuple-element", "bitcast"}
+    return [x for x in materialized(text) if size(x[1]) in weights and x[2] not in allowed]
+
+
 def test_serve_decode_step_updates_cache_in_place(one_chip, monkeypatch):
     """The engine's decode step (cache donated) at the serve cell's shapes:
     Mixtral 8x7B widths, 2 layers, 16 slots of 2048 f32 positions. Every
     cache leaf shares its buffer with an output, and outside fusions no
     instruction makes an array of a layer's or of the stack's K/V dims, in
     any axis order, but the in-place row update; the attention is the
-    kernel."""
+    kernel. The experts' FFN is the expert kernel, which reads the
+    stacked expert weights in place: no array of a layer's or the stack's
+    expert-weight shape is made."""
     import dataclasses
     import functools
 
@@ -159,26 +205,24 @@ def test_serve_decode_step_updates_cache_in_place(one_chip, monkeypatch):
     monkeypatch.setattr(attention, "default_impl", lambda: "pallas")
     monkeypatch.setattr(attention, "decode_attention",
                         functools.partial(decode.decode_attention, interpret=False))
+    _steer_to_expert_kernel(monkeypatch)
     cfg = dataclasses.replace(get_config("mixtral-8x7b"), n_layers=2)
     B, S = 16, 2048
-    on = lambda tree: jax.tree.map(
-        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip), tree)
-    params = on(jax.eval_shape(lambda: M.init_params(jax.random.key(0), cfg)))
-    cache = on(jax.eval_shape(lambda: M.init_cache(cfg, B, S, dtype=jnp.float32)))
-    vec = jax.ShapeDtypeStruct((B,), jnp.int32, sharding=one_chip)
-    step = jax.jit(lambda p, c, b, pos: M.decode_step(p, c, b, pos, cfg),
-                   donate_argnums=(1,))  # as ``Engine`` jits it
-    text = step.lower(params, cache, {"token": vec}, vec).compile().as_text()
+    cache = jax.eval_shape(lambda: M.init_cache(cfg, B, S, dtype=jnp.float32))
+    text = _engine_step_text(cfg, B, S, one_chip)
 
     leaves = jax.tree.leaves(cache)
     assert aliased_parameter_dims(text) == sorted(leaf.shape for leaf in leaves)
     size = lambda dims: tuple(sorted(d for d in dims if d != 1))
-    kv_sizes = {size(s) for leaf in leaves for s in (leaf.shape, leaf.shape[1:])}
+    kv_sizes = {size(s) for leaf in jax.tree.leaves(cache["stack"])
+                for s in (leaf.shape, leaf.shape[1:])}
     made = [m for m in materialized(text) if size(m[1]) in kv_sizes]
     allowed = {"parameter", "get-tuple-element", "bitcast", "fusion:scatter"}
     assert [m for m in made if m[2] not in allowed] == []
     assert any(kind == "fusion:scatter" for _, _, kind in made)
     assert 'custom_call_target="tpu_custom_call"' in text
+    assert re.search(r"%expert_ffn\S* = .*tpu_custom_call", text)
+    assert _expert_weights_made(text, cfg) == []
 
 
 def test_mla_decode_attention_compiles(one_chip):
@@ -186,7 +230,6 @@ def test_mla_decode_attention_compiles(one_chip):
     cache (DeepSeek-V2-Lite: 27 layers, 32 slots of 2048 positions, rows
     of 512 + 64 padded to 640, 16 heads): no copy and no scratch in HBM,
     and the kernel's instruction is named for the trace."""
-    import re
 
     from repro.kernels.flash_attention.mla_decode import mla_decode_attention
 
@@ -203,6 +246,28 @@ def test_mla_decode_attention_compiles(one_chip):
     assert compiled.memory_analysis().temp_size_in_bytes == 0
 
 
+# (L, E, C, d, f): the serve cells' expert stacks, Mixtral 8x7B (2 layers,
+# 8 experts) and DeepSeek-V2-Lite's share (26 MoE layers, 8 held)
+EXPERT_SHAPES = {"mixtral": (2, 8, 16, 4096, 14336), "deepseek": (26, 8, 16, 2048, 1408)}
+
+
+@pytest.mark.parametrize("arch", sorted(EXPERT_SHAPES))
+def test_expert_ffn_compiles(one_chip, arch):
+    """The expert-FFN kernel over a serve cell's stacked bf16 expert
+    weights: it fits the VMEM it asks for, uses no scratch in HBM, and its
+    instruction is named for the trace."""
+    from repro.kernels.moe.expert_ffn import expert_ffn
+
+    L, E, C, d, f = EXPERT_SHAPES[arch]
+    sds = lambda *shape, dt=jnp.bfloat16: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+    compiled = jax.jit(lambda x, g, i, o, layer, experts, n: expert_ffn(
+        x, g, i, o, layer, experts, n, interpret=False)).lower(
+        sds(E, C, d), sds(L, E, d, f), sds(L, E, d, f), sds(L, E, f, d),
+        sds(dt=jnp.int32), sds(E, dt=jnp.int32), sds(dt=jnp.int32)).compile()
+    assert re.search(r"%expert_ffn\S* = .*tpu_custom_call", compiled.as_text())
+    assert compiled.memory_analysis().temp_size_in_bytes == 0
+
+
 def test_mla_serve_decode_step_reads_the_latent_cache_in_place(one_chip, monkeypatch):
     """The engine's decode step (cache donated) at the longgen cell's
     shapes: DeepSeek-V2-Lite at its published widths, 27 layers (the dense
@@ -211,10 +276,10 @@ def test_mla_serve_decode_step_reads_the_latent_cache_in_place(one_chip, monkeyp
     instruction makes an array of a layer's or of the stack's latent dims
     but the in-place row update; no array anywhere has the (slots,
     positions, heads) dims of keys or values expanded from the latent
-    rows; and the attention is the MLA kernel."""
+    rows; the attention is the MLA kernel; and the held experts' FFN is
+    the expert kernel, with no array of an expert-weight shape made."""
     import dataclasses
     import functools
-    import re
 
     from _hlo import aliased_parameter_dims, materialized
     from repro.configs import get_config
@@ -225,21 +290,16 @@ def test_mla_serve_decode_step_reads_the_latent_cache_in_place(one_chip, monkeyp
     monkeypatch.setattr(attention, "default_impl", lambda: "pallas")
     monkeypatch.setattr(attention, "mla_decode_attention",
                         functools.partial(mla_decode.mla_decode_attention, interpret=False))
+    _steer_to_expert_kernel(monkeypatch)
     base = get_config("deepseek-v2-lite")
     cfg = dataclasses.replace(base, moe=dataclasses.replace(base.moe, held_experts=(0, 8)))
     B, S = 32, 2048
-    on = lambda tree: jax.tree.map(
-        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip), tree)
-    params = on(jax.eval_shape(lambda: M.init_params(jax.random.key(0), cfg)))
-    cache = on(jax.eval_shape(lambda: M.init_cache(cfg, B, S, dtype=jnp.float32)))
-    vec = jax.ShapeDtypeStruct((B,), jnp.int32, sharding=one_chip)
-    step = jax.jit(lambda p, c, b, pos: M.decode_step(p, c, b, pos, cfg),
-                   donate_argnums=(1,))  # as ``Engine`` jits it
-    text = step.lower(params, cache, {"token": vec}, vec).compile().as_text()
+    cache = jax.eval_shape(lambda: M.init_cache(cfg, B, S, dtype=jnp.float32))
+    text = _engine_step_text(cfg, B, S, one_chip)
 
     leaves = jax.tree.leaves(cache)
     assert aliased_parameter_dims(text) == sorted(leaf.shape for leaf in leaves)
-    latent_dims = {d for leaf in leaves for d in (leaf.shape, leaf.shape[1:])}
+    latent_dims = {d for leaf in leaves if leaf.ndim for d in (leaf.shape, leaf.shape[1:])}
     made = [m for m in materialized(text) if m[1] in latent_dims]
     allowed = {"parameter", "get-tuple-element", "bitcast", "fusion:scatter"}
     assert [m for m in made if m[2] not in allowed] == []
@@ -248,3 +308,5 @@ def test_mla_serve_decode_step_reads_the_latent_cache_in_place(one_chip, monkeyp
                 if d.startswith(f"{B},{S},{H},") or d.startswith(f"{B},{H},{S},")]
     assert expanded == []
     assert re.search(r"%mla_decode\S* = .*tpu_custom_call", text)
+    assert re.search(r"%expert_ffn\S* = .*tpu_custom_call", text)
+    assert _expert_weights_made(text, cfg) == []
