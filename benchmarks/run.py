@@ -648,94 +648,6 @@ def bench_moe_pipeline(log=print):
     tuner.save()
 
 
-def bench_multitenant_serving(log=print):
-    """Multi-tenant serving: two mixtral-smoke tenants decode through ONE
-    combined host program per MoE boundary round vs the time-multiplexed
-    control (same tenants, one solo pipelined replay each). Runs on the
-    jax ppermute backend (8 of the forced host devices) where replayed
-    rounds cost real wall-clock, so the deterministic round-count win
-    (combined rounds = max over guests, muxed = sum) shows up directly as
-    serving throughput.
-
-    Asserted in-line: every tenant's tokens are bit-exact against a
-    single-tenant fleet through the same replay path (both arms), and the
-    combined fleet's per-token latency strictly beats time-muxed (min over
-    3 fresh-fleet episodes). ``multitenant_serving_decision`` records the
-    autotuner's combined-site pick for this guest set, keyed on the
-    guest-set signature."""
-    import time as _time
-
-    import jax
-
-    from repro.configs import get_smoke_config
-    from repro.models import model as M
-    from repro.runtime.autotune import Autotuner
-    from repro.serve.fleet import TenantFleet
-
-    tag = "tenants=2,host=2x2,guest=1x2,arch=mixtral-smoke"
-    if jax.device_count() < 8:
-        for path in ("combined", "time_mux"):
-            log(f"multitenant_serving,path={path},{tag},skipped=need_8_devices")
-        return
-
-    cfg = get_smoke_config("mixtral-8x7b")
-    params = [M.init_params(jax.random.key(i), cfg) for i in range(2)]
-    prompts = [[5, 6, 7], [9, 10]]
-    n_new = 6
-
-    def episode(combined, idxs=(0, 1)):
-        fleet = TenantFleet((2, 2), backend="jax", max_seq=32,
-                            combined=combined)
-        reqs = [
-            fleet.submit(
-                fleet.admit_model(cfg, params[i], guest=(1, 2), slots=2),
-                prompts[i], n_new)
-            for i in idxs
-        ]
-        t0 = _time.perf_counter()
-        fleet.run_to_completion()
-        dt = _time.perf_counter() - t0
-        assert all(r.done for r in reqs)
-        return fleet, [r.out for r in reqs], dt
-
-    solo = [episode(True, idxs=(i,))[1][0] for i in range(2)]
-    best: dict[str, tuple] = {}
-    for path, combined in (("combined", True), ("time_mux", False)):
-        episode(combined)  # warm the lru-cached program combine/lowering
-        fleet, dt = None, float("inf")
-        for _ in range(3):
-            f, outs, d = episode(combined)
-            assert outs == solo, (
-                f"{path} fleet not bit-exact vs solo: {outs} != {solo}")
-            if d < dt:
-                fleet, dt = f, d
-        us_tok = dt * 1e6 / fleet.tokens_out
-        best[path] = (fleet, us_tok)
-        log(f"multitenant_serving,path={path},{tag},replays={fleet.replays},"
-            f"rounds={fleet.rounds_replayed},tokens={fleet.tokens_out},"
-            f"us_per_call={us_tok:.0f}")
-    comb, mux = best["combined"], best["time_mux"]
-    assert comb[0].rounds_replayed < mux[0].rounds_replayed, (
-        comb[0].rounds_replayed, mux[0].rounds_replayed)
-    assert comb[1] < mux[1], (
-        f"combined fleet lost to time-mux: {comb[1]:.0f}us/token "
-        f"vs {mux[1]:.0f}us/token")
-    print(f"# combined serves {1e6 / comb[1]:.0f} tok/s vs "
-          f"{1e6 / mux[1]:.0f} tok/s time-muxed "
-          f"({mux[1] / comb[1]:.2f}x)")
-
-    # the combined-site decision for this guest set (analytic mode keeps
-    # the recorded strategy deterministic across hosts)
-    rep = comb[0].collective_report(tuner=Autotuner(mode="analytic"))
-    assert rep["status"] == "ok", rep
-    assert rep["combined_rounds"] < rep["time_mux_rounds"], rep
-    log(f"multitenant_serving_decision,{tag},"
-        f"combined_rounds={rep['combined_rounds']},"
-        f"time_mux_rounds={rep['time_mux_rounds']},"
-        f"strategy={rep['strategy']},source={rep['source']},"
-        f"us_per_call={rep['analytic_us'][rep['strategy']]:.0f}")
-
-
 def bench_elastic_failover(log=print):
     """Elastic training failover: the detection -> resume wall time and
     the §5 redistribution broadcast's round count for every stage of a
@@ -947,8 +859,6 @@ def main(argv=None) -> None:
     bench_export(log)
     print("# ---- pipelined shard-path dispatch (waves overlapped with expert FFN)")
     bench_moe_pipeline(log)
-    print("# ---- multi-tenant serving (combined fleet vs time-multiplexed)")
-    bench_multitenant_serving(log)
     print("# ---- elastic failover (rewrite-only recovery + §5 re-shard)")
     bench_elastic_failover(log)
     bench_core_micro(log)

@@ -165,8 +165,7 @@ def concurrent_program(
     fused-table form — the stacked-σ tables then span all guests.
     ``pipelined`` (alltoall guests only) combines each guest's
     Schedule-``offset`` pipelined variant, so the combined program's stages
-    keep real launch stamps for the overlapped executors — this is the form
-    the multi-tenant serving fleet replays at every MoE boundary."""
+    keep real launch stamps for the overlapped executors."""
     from repro.runtime.combine import combine
 
     if roots is not None and len(roots) != len(embeddings):

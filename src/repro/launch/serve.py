@@ -7,13 +7,6 @@ CPU-scale (the reduced smoke config, the default):
 Published widths with the depth cut (``--full --layers N``), on a chip:
     PYTHONPATH=src python -m repro.launch.serve --arch mixtral-8x7b --full \\
         --layers 2 --requests 4 --max-new 16
-
-``--tenants N`` switches to the multi-tenant fleet: N independently-seeded
-copies of the arch seated as disjoint D3(1,2) guests on one D3(K,M) host,
-every model's MoE dispatch riding ONE combined program per boundary round
-(``--time-mux`` serves the same tenants through sequential solo replays
-instead, for comparison). Fleet mode needs an MoE arch, e.g.
-``--arch mixtral-8x7b``.
 """
 
 from __future__ import annotations
@@ -75,44 +68,6 @@ def serve_single(cfg, args):
     return eng, done
 
 
-def _serve_fleet(cfg, args):
-    from repro.serve.fleet import TenantFleet
-
-    if getattr(cfg, "moe", None) is None:
-        raise SystemExit(
-            f"--tenants needs an MoE arch (got {args.arch}): fleet tenants "
-            "share the combined program at their expert-dispatch boundaries")
-    fleet = TenantFleet((args.tenants, 2), max_seq=args.max_seq,
-                        combined=not args.time_mux)
-    rng = np.random.default_rng(args.seed)
-    submitted = []
-    for i in range(args.tenants):
-        params = M.init_params(jax.random.key(args.seed + i), cfg)
-        tid = fleet.admit_model(cfg, params, guest=(1, 2), slots=args.slots)
-        for req in _random_prompts(rng, cfg, args.requests, args.max_new):
-            submitted.append(fleet.submit(tid, req.prompt, req.max_new_tokens))
-        print(f"admitted tenant {tid} with {args.requests} requests")
-    t0 = time.perf_counter()
-    fleet.run_to_completion()
-    dt = time.perf_counter() - t0
-    done = [r for r in submitted if r.done]
-    assert len(done) == len(submitted), (
-        f"{len(submitted) - len(done)} requests lost by the fleet loop")
-    mode = "time_mux" if args.time_mux else "combined"
-    print(f"completed {len(done)}/{len(submitted)} requests across "
-          f"{args.tenants} tenants ({mode})")
-    print(f"fleet steps: {fleet.steps_run}, replays: {fleet.replays}, "
-          f"rounds: {fleet.rounds_replayed}, wall: {dt:.2f}s, "
-          f"tokens: {fleet.tokens_out}, "
-          f"tokens/s: {fleet.tokens_out / max(dt, 1e-9):.1f}")
-    report = fleet.collective_report()
-    print(f"combined-site decision: {report.get('key')} -> "
-          f"{report.get('strategy')} ({report.get('source')}); "
-          f"rounds combined={report.get('combined_rounds')} "
-          f"vs time_mux={report.get('time_mux_rounds')}")
-    return fleet.steps_run
-
-
 def main(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", choices=ARCH_IDS, default="tinyllama-1.1b")
@@ -125,12 +80,6 @@ def main(argv=None):
     ap.add_argument("--max-new", type=int, default=12)
     ap.add_argument("--max-seq", type=int, default=128)
     ap.add_argument("--seed", type=int, default=0)
-    ap.add_argument("--tenants", type=int, default=0,
-                    help="serve N copies of the arch as a multi-tenant fleet "
-                         "on one D3(N,2) host (0 = single-engine mode)")
-    ap.add_argument("--time-mux", action="store_true",
-                    help="fleet mode: replay each tenant's solo program "
-                         "sequentially instead of the combined program")
     args = ap.parse_args(argv)
 
     enable_compile_cache()
@@ -139,8 +88,6 @@ def main(argv=None):
         cfg = dataclasses.replace(cfg, n_layers=args.layers)
     if cfg.embeds_input:
         raise SystemExit("stub-frontend archs serve via decode_step directly")
-    if args.tenants:
-        return _serve_fleet(cfg, args)
     return serve_single(cfg, args)
 
 

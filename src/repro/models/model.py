@@ -5,8 +5,6 @@ decode step. All entry points are pure functions of (params, batch).
 
 from __future__ import annotations
 
-import functools
-
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
@@ -161,7 +159,7 @@ def init_cache(cfg: ModelConfig, batch: int, max_seq: int, dtype=None):
     """Every layer's decode state for ``batch`` slots of ``max_seq``
     positions, and ``experts_run``: the held experts the MoE layers have
     run since, summed over steps and layers (int32, wrapping; counted by
-    ``decode_step``, not by the staged decode)."""
+    ``decode_step``)."""
     dt = dtype or jnp.dtype(cfg.compute_dtype)
     cache = {"stack": T.stack_cache_init(cfg, batch, max_seq, dt),
              "experts_run": jnp.zeros((), jnp.int32)}
@@ -229,45 +227,6 @@ def decode_step(params, cache, batch, position, cfg: ModelConfig, unroll: bool =
                                          mrope, unroll, batch.get("live"))
     new_cache["stack"] = nsc
     new_cache["experts_run"] = cache["experts_run"] + experts_run
-    h = T.norm_fn(cfg)(params["final_norm"], x)
-    logits = _unembed(params, h, cfg)
-    return logits[:, 0], new_cache
-
-
-def decode_step_staged(params, cache, batch, position, cfg: ModelConfig):
-    """Generator twin of ``decode_step`` that pauses at every MoE boundary.
-
-    Same contract as ``decode_step`` — but instead of computing expert FFNs
-    inline it delegates to ``transformer.stack_decode_staged``, yielding
-    ``(ffn_params, h2)`` at each MoE member and expecting the expert output
-    sent back. Drive it with ``next()`` / ``gen.send(y)``; the final
-    ``StopIteration.value`` is ``(logits (B, vocab), new_cache)``.
-
-    The dense prefix (deepseek ``first_dense_layers``) has no MoE members
-    and runs eagerly up front; mixers inside the stack run jitted. This is
-    the forward the multi-tenant ``serve.fleet`` engines use so N tenants'
-    expert dispatches can share one combined host program per boundary.
-    """
-    if cfg.embeds_input and "embed" in batch:
-        x = batch["embed"][:, None].astype(jnp.dtype(cfg.compute_dtype))
-    else:
-        x = L.embed_apply(params["embed"], batch["token"][:, None]).astype(
-            jnp.dtype(cfg.compute_dtype)
-        )
-    mrope = batch.get("mrope_positions")
-    new_cache = dict(cache)
-    if cfg.first_dense_layers:
-        pc = cache["prefix"][0]
-        for i in range(cfg.first_dense_layers):
-            x, pc, _ = T.member_decode(
-                jax.tree.map(lambda a: a[i], params["prefix"][0]), x, pc, i,
-                cfg, "attn", "mlp", position, mrope,
-            )
-        new_cache["prefix"] = (pc,)
-    x, nsc = yield from T.stack_decode_staged(
-        params["stack"], x, cache["stack"], cfg, position, mrope
-    )
-    new_cache["stack"] = nsc
     h = T.norm_fn(cfg)(params["final_norm"], x)
     logits = _unembed(params, h, cfg)
     return logits[:, 0], new_cache

@@ -5,8 +5,6 @@ expert-parallel path that uses the paper's doubly-parallel all-to-all.
 
 from __future__ import annotations
 
-import dataclasses
-
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -504,159 +502,3 @@ def load_balance_loss(logits, idx, E, k):
     p_mean = probs.mean(axis=0)
     f = jax.nn.one_hot(idx, E, dtype=jnp.float32).sum(axis=(0, 1)) / (idx.shape[0] * k)
     return E * jnp.sum(f * p_mean)
-
-
-# ---------------------------------------------------------------------------
-# Guest-embedded dispatch: the whole-array §3 form for multi-tenant serving.
-#
-# A tenant admitted as a D3(J,L) guest on a D3(K,M) host routes its expert
-# dispatch+combine through a PROGRAM REPLAY instead of a shard_map
-# collective: ``moe_guest_dispatch`` packs the batch's capacity buffers
-# into an (n_guest, n_guest, E_loc, C, d) §3 dispatch array (all tokens
-# sourced at guest device 0, expert shards spread over all guest devices),
-# a backend ``run_alltoall_compute`` round trip computes each chunk's
-# expert FFN AT its destination device (``guest_expert_ffn``), and
-# ``moe_guest_combine`` gathers the returned buffers back per token. The
-# routing math — top-k, running capacity slots, overflow drops — is the
-# ``moe_apply_sparse`` formulation verbatim, in NumPy, because it runs
-# host-side AROUND the replay (the replay itself carries N tenants at once
-# through one combined host program; see serve/fleet.py).
-# ---------------------------------------------------------------------------
-
-
-def guest_capacity(m, T: int) -> int:
-    """Per-expert capacity for T routed tokens — the ``moe_apply_sparse``
-    bound (cf·T·k/E, rounded up to a multiple of 16)."""
-    C = max(1, int(m.capacity_factor * T * m.top_k / m.num_experts))
-    return -(-C // 16) * 16
-
-
-def _np_softmax(v: np.ndarray) -> np.ndarray:
-    v = v - v.max(axis=-1, keepdims=True)
-    e = np.exp(v)
-    return e / e.sum(axis=-1, keepdims=True)
-
-
-def _np_silu(v: np.ndarray) -> np.ndarray:
-    # x·sigmoid(x) via tanh — stable for both signs, no exp overflow
-    return v * (0.5 * (1.0 + np.tanh(0.5 * v)))
-
-
-@dataclasses.dataclass
-class GuestDispatchState:
-    """Everything ``moe_guest_combine`` needs to invert a dispatch: the
-    router weights and capacity-slot assignment of each (token, k) pair,
-    plus the shapes to unflatten back to."""
-
-    w: np.ndarray        # (T, top_k) router weights
-    flat_e: np.ndarray   # (T·top_k,) expert index per assignment
-    slot: np.ndarray     # (T·top_k,) capacity slot within the expert buffer
-    keep: np.ndarray     # (T·top_k,) False = dropped by the capacity bound
-    src: np.ndarray      # (T·top_k,) source token index
-    shape: tuple         # (B, S, d) of the dispatched activations
-    C: int
-    E_loc: int
-
-
-def moe_guest_dispatch(params, x, cfg, n_guest: int):
-    """Route (B, S, d) activations into the whole-array guest dispatch form.
-
-    Returns ``(X, state)`` where X is (n_guest, n_guest, E_loc, C, d) with
-    X[0, j] = the capacity chunks bound for guest device j's experts (all
-    tokens live on guest source device 0 — a decode batch is one data
-    shard) and zero elsewhere. A ``run_alltoall_compute`` round trip then
-    yields back[0, j] = FFN_j(X[0, j]); feed that to ``moe_guest_combine``.
-    Requires E % n_guest == 0 (each guest device owns E/n_guest experts).
-    """
-    m = cfg.moe
-    x = np.asarray(x, np.float32)
-    B, S, d = x.shape
-    T = B * S
-    E = m.num_experts
-    if E % n_guest:
-        raise ValueError(
-            f"E={E} experts do not shard over {n_guest} guest devices"
-        )
-    E_loc = E // n_guest
-    C = guest_capacity(m, T)
-    xt = x.reshape(T, d)
-    logits = xt @ np.asarray(params["router"], np.float32)
-    probs = _np_softmax(logits)
-    # stable argsort on -probs = first-index tie-break, same as lax.top_k
-    idx = np.argsort(-probs, axis=-1, kind="stable")[:, : m.top_k]
-    w = np.take_along_axis(probs, idx, axis=-1)
-    if m.norm_topk_probs:
-        w = w / np.clip(w.sum(-1, keepdims=True), 1e-9, None)
-    flat_e = idx.reshape(-1)
-    onehot = np.eye(E, dtype=np.int64)[flat_e]
-    slot = ((np.cumsum(onehot, axis=0) - 1) * onehot).sum(-1)
-    keep = slot < C
-    src = np.repeat(np.arange(T), m.top_k)
-    buf = np.zeros((E, C, d), np.float32)
-    # (expert, slot) pairs are unique by construction (slot is the running
-    # per-expert count), so this is a pure scatter, not an accumulation
-    buf[flat_e[keep], slot[keep]] = xt[src[keep]]
-    X = np.zeros((n_guest, n_guest, E_loc, C, d), np.float32)
-    X[0] = buf.reshape(n_guest, E_loc, C, d)
-    state = GuestDispatchState(
-        w=w, flat_e=flat_e, slot=slot, keep=keep, src=src,
-        shape=(B, S, d), C=C, E_loc=E_loc,
-    )
-    return X, state
-
-
-def moe_guest_combine(back, state: GuestDispatchState, params, x):
-    """Invert ``moe_guest_dispatch``: gather each token's expert outputs
-    from the returned (n_guest, n_guest, E_loc, C, d) round-trip array
-    (rows back[0, :]), weight by the router gates, add shared experts.
-    Returns (B, S, d) float32."""
-    B, S, d = state.shape
-    T = B * S
-    y_buf = np.asarray(back, np.float32)[0].reshape(-1, state.C, d)  # (E, C, d)
-    y = np.zeros((T, d), np.float32)
-    g = y_buf[state.flat_e[state.keep], state.slot[state.keep]]
-    np.add.at(y, state.src[state.keep],
-              g * state.w.reshape(-1)[state.keep, None])
-    if "shared" in params:
-        xt = np.asarray(x, np.float32).reshape(T, d)
-        y = y + np.asarray(
-            L.mlp_apply(params["shared"], jnp.asarray(xt)), np.float32
-        )
-    return y.reshape(B, S, d)
-
-
-def guest_expert_shards(params, n_guest: int):
-    """Per-guest-device expert weight shards as NumPy views:
-    (w_in, w_gate) each (n_guest, E_loc, d, f) and w_out (n_guest, E_loc,
-    f, d) — row g is what guest device g's ``guest_expert_ffn`` closes
-    over."""
-    E = params["w_in"].shape[0]
-    if E % n_guest:
-        raise ValueError(f"E={E} does not shard over {n_guest} guest devices")
-
-    def shard(a):
-        a = np.asarray(a, np.float32)
-        return a.reshape(n_guest, E // n_guest, *a.shape[1:])
-
-    return shard(params["w_in"]), shard(params["w_gate"]), shard(params["w_out"])
-
-
-def guest_expert_ffn_np(chunks, w_in, w_gate, w_out):
-    """One device's silu-gated expert FFN over arriving capacity chunks —
-    the NumPy reference-replay compute. ``chunks`` (..., E_loc, C, d) with
-    this device's (E_loc, d, f) / (E_loc, f, d) shards; batched over any
-    leading dims (a replay hands the whole arrival stack at once)."""
-    h = _np_silu(np.einsum("...ecd,edf->...ecf", chunks, w_gate)) * np.einsum(
-        "...ecd,edf->...ecf", chunks, w_in
-    )
-    return np.einsum("...ecf,efd->...ecd", h, w_out)
-
-
-def guest_expert_ffn(chunks, w_in, w_gate, w_out):
-    """``guest_expert_ffn_np`` in jnp — the stable compute callable for the
-    JAX backend's ``run_alltoall_compute(weights=...)`` path (module-level
-    so the compiled shard_map closure caches across calls)."""
-    h = jax.nn.silu(jnp.einsum("...ecd,edf->...ecf", chunks, w_gate)) * jnp.einsum(
-        "...ecd,edf->...ecf", chunks, w_in
-    )
-    return jnp.einsum("...ecf,efd->...ecd", h, w_out)

@@ -100,12 +100,17 @@ def member_train(params, x, cfg, mixer, ffn, positions, mrope_positions, use_ker
     return x, aux
 
 
-def member_decode_mixer(params, x, cache, layer, cfg, mixer, position, mrope_positions):
-    """The mixer half of one decode member: pre-norm mixer + residual.
+def member_decode(params, x, cache, layer, cfg, mixer, ffn, position, mrope_positions,
+                  live=None):
+    """One decode member: pre-norm mixer + residual, then the FFN.
     ``cache`` is the member's, every layer's (leading axis): attention
     writes the new token's rows into layer ``layer`` and reads the layer
-    where it lies; a recurrent mixer's new state replaces its layer's.
-    Returns (x, cache) — the FFN half (if any) applies on top."""
+    where it lies; a recurrent mixer's new state replaces its layer's. An
+    MoE member's expert weights are every layer's, stacked
+    (``stack_decode`` keeps them out of the layer loop's slices), and its
+    routing skips the tokens of rows where ``live`` is False
+    (``moe.moe_decode``). Returns (x, cache, the held experts run: 0 but
+    for an MoE member)."""
     norm = norm_fn(cfg)
     h = norm(params["norm1"], x)
     if mixer == "attn":
@@ -115,44 +120,19 @@ def member_decode_mixer(params, x, cache, layer, cfg, mixer, position, mrope_pos
             mx, cache = A.gqa_decode(
                 params["mixer"], h, cache, layer, cfg, position, mrope_positions
             )
-        return x + mx, cache
-    state = jax.tree.map(lambda a: a[layer], cache)
-    if mixer == "mamba":
-        mx, state = MB.mamba_decode(params["mixer"], h, state, cfg)
-    elif mixer == "mlstm":
-        mx, state = XL.mlstm_decode(params["mixer"], h, state, cfg)
     else:
-        mx, state = XL.slstm_decode(params["mixer"], h, state, cfg)
-    cache = jax.tree.map(lambda c, s: c.at[layer].set(s.astype(c.dtype)), cache, state)
-    return x + mx, cache
-
-
-@functools.lru_cache(maxsize=None)
-def mixer_decode_jit(cfg, mixer):
-    """Jitted ``member_decode_mixer`` per (config, mixer kind) — the staged
-    decode path (``stack_decode_staged``) runs the mixers compiled even
-    though the generator itself is eager Python. mrope-free (token serving);
-    callers with mrope positions fall back to the eager form."""
-
-    def fn(params, x, cache, layer, position):
-        return member_decode_mixer(params, x, cache, layer, cfg, mixer, position, None)
-
-    return jax.jit(fn)
-
-
-def member_decode(params, x, cache, layer, cfg, mixer, ffn, position, mrope_positions,
-                  live=None):
-    """One decode member: the mixer, then the FFN. An MoE member's expert
-    weights are every layer's, stacked (``stack_decode`` keeps them out of
-    the layer loop's slices), and its routing skips the tokens of rows
-    where ``live`` is False (``moe.moe_decode``). Returns (x, cache, the
-    held experts run: 0 but for an MoE member)."""
-    x, cache = member_decode_mixer(
-        params, x, cache, layer, cfg, mixer, position, mrope_positions
-    )
+        state = jax.tree.map(lambda a: a[layer], cache)
+        if mixer == "mamba":
+            mx, state = MB.mamba_decode(params["mixer"], h, state, cfg)
+        elif mixer == "mlstm":
+            mx, state = XL.mlstm_decode(params["mixer"], h, state, cfg)
+        else:
+            mx, state = XL.slstm_decode(params["mixer"], h, state, cfg)
+        cache = jax.tree.map(lambda c, s: c.at[layer].set(s.astype(c.dtype)), cache, state)
+    x = x + mx
     n_run = jnp.int32(0)
     if ffn != "none":
-        h2 = norm_fn(cfg)(params["norm2"], x)
+        h2 = norm(params["norm2"], x)
         if ffn == "moe":
             y, n_run = MOE.moe_decode(params["ffn"], h2, cfg, live, layer)
         else:
@@ -281,51 +261,6 @@ def stack_decode(stack_params, x, caches, cfg, position, mrope_positions=None,
         else:
             carry, _ = jax.lax.scan(group_fn, carry, (sliced, jnp.arange(cfg.n_groups)))
     return carry
-
-
-def stack_decode_staged(stack_params, x, caches, cfg, position, mrope_positions=None):
-    """Generator twin of ``stack_decode`` that SUSPENDS at every MoE member:
-    instead of computing the expert FFN inline, it yields ``(ffn_params,
-    h2)`` — the member's expert weights and its post-norm2 hidden — and
-    expects the expert output ``y`` sent back (``gen.send(y)``), which it
-    adds to the residual stream exactly where ``member_decode`` would.
-
-    This is the seam multi-tenant serving cuts the forward at: the driver
-    (``serve.fleet.TenantFleet``) collects the yields of N tenants' staged
-    decodes and services them all with ONE combined host program replay per
-    boundary round. Mixers run through the jitted ``mixer_decode_jit``
-    (eager fallback when mrope positions are present); everything outside
-    the MoE members is the same math as ``stack_decode(unroll=True)``.
-
-    Returns (x, new_caches) via StopIteration.value: each mixer writes its
-    layer of the stacked caches, as in ``stack_decode``.
-    """
-    pattern = cfg.layer_kinds()
-    norm = norm_fn(cfg)
-    caches = list(caches)
-    for g in range(cfg.n_groups):
-        group_params = jax.tree.map(lambda a: a[g], stack_params)
-        for mi, (mixer, ffn) in enumerate(pattern):
-            if mrope_positions is None:
-                x, caches[mi] = mixer_decode_jit(cfg, mixer)(
-                    group_params[mi], x, caches[mi], g, position
-                )
-            else:
-                x, caches[mi] = member_decode_mixer(
-                    group_params[mi], x, caches[mi], g, cfg, mixer,
-                    position, mrope_positions,
-                )
-            if ffn == "moe":
-                h2 = norm(group_params[mi]["norm2"], x)
-                y = yield (group_params[mi]["ffn"], h2)
-                x = x + jnp.asarray(y, x.dtype)
-            elif ffn != "none":
-                h2 = norm(group_params[mi]["norm2"], x)
-                x = x + L.mlp_apply(
-                    group_params[mi]["ffn"], h2,
-                    act=jax.nn.silu if cfg.mlp_gated else jax.nn.gelu,
-                )
-    return x, tuple(caches)
 
 
 def stack_cache_init(cfg, batch, max_seq, dtype):

@@ -68,8 +68,8 @@ guest-ordered host image. The pass guarantees:
 Concurrent-guest guarantees (``combine.combine(programs)``)
 -----------------------------------------------------------
 N rewritten guest programs with pairwise-disjoint ``active_devices``
-images merge into ONE host program (multi-tenant serving of disjoint
-D3(J,L) workloads on one mesh). What ``combine`` adds to the contract:
+images merge into ONE host program (disjoint D3(J,L) workloads on one
+mesh). What ``combine`` adds to the contract:
 
   * the combined program is an ordinary emulated program —
     ``active_devices`` is the guests' images concatenated in argument

@@ -22,8 +22,8 @@ hardcoded one strategy. The ``Autotuner`` closes that gap:
         only — the fused wave pipeline that overlaps dispatch with the
         per-destination compute; priced with the max-of-overlap discount
         when the key carries a ``compute_us`` term)
-      - ``site="combined"`` (N disjoint guests on one host — the
-        multi-tenant fleet's boundary replays): combined | time_mux.
+      - ``site="combined"`` (N disjoint guests on one host):
+        combined | time_mux.
         ``combined`` is ONE merged-program replay at makespan
         max(T_1..T_N); ``time_mux`` is N sequential solo replays at
         ΣT_i. Keyed on the guest-set signature (``decide_combined``),
@@ -178,7 +178,7 @@ class Decision:
 def _default_strategy(kind: str, site: str) -> str:
     """What each call site did BEFORE the autotuner existed (mode='off')."""
     if site == "combined":
-        return "time_mux"  # pre-fleet behavior: every tenant served alone
+        return "time_mux"  # every guest replayed alone
     return "xla" if site == "shard" else "loop"
 
 
@@ -566,8 +566,7 @@ def _measure_combined_closure(kind: str, strategy: str, embeddings,
     kind has no device-free replay to time. Both arms replay on the NumPy
     reference backend (host-site style: deterministic, no device quorum):
     ``combined`` is ONE merged-program replay, ``time_mux`` is every
-    guest's solo emulated replay back to back — the exact pair of
-    executions the multi-tenant fleet chooses between."""
+    guest's solo emulated replay back to back."""
     if kind not in ("alltoall", "allreduce"):
         return None
     from repro.dist import collectives as coll

@@ -1,8 +1,5 @@
-"""Serving substrate: batched decode engine with continuous batching, and
-the multi-tenant fleet that serves N models through one combined host
-program."""
+"""Serving substrate: batched decode engine with continuous batching."""
 
 from repro.serve.engine import Engine, Request
-from repro.serve.fleet import FleetEngine, Tenant, TenantFleet
 
-__all__ = ["Engine", "Request", "FleetEngine", "Tenant", "TenantFleet"]
+__all__ = ["Engine", "Request"]

@@ -150,10 +150,8 @@ class Engine:
     # -------------------------------------------------------------- step
     def _forward(self) -> np.ndarray:
         """One batched model forward over all slots (the seam subclasses
-        override — ``serve.fleet.FleetEngine`` runs the staged decode here
-        so MoE boundaries can be serviced by a combined host program).
-        Returns each slot's greedy next token on the host (slots,) and
-        updates ``self.cache``."""
+        override). Returns each slot's greedy next token on the host
+        (slots,) and updates ``self.cache``."""
         tokens = self._dispatch()
         with TraceAnnotation("engine.fetch"):
             # the ids and the cache's running count of experts run, together

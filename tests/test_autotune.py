@@ -303,7 +303,7 @@ def test_moe_site_report_shapes(tmp_path):
 
 
 # ------------------------------------------------------ combined guest site
-def _fleet_embeddings():
+def _tenant_embeddings():
     from repro.core.emulation import disjoint_embeddings
     from repro.core.topology import D3
 
@@ -314,7 +314,7 @@ def test_decide_combined_key_and_measured_win(tmp_path):
     """The combined site class: keyed on the guest-set signature, measured
     via reference replays of BOTH arms, and on disjoint same-shape guests
     the merged program wins (max vs sum of rounds)."""
-    embs = _fleet_embeddings()
+    embs = _tenant_embeddings()
     tuner = at.Autotuner(cache_path=tmp_path / "c.json")
     dec = tuner.decide_combined("alltoall", embs, nbytes=4096)
     assert str(dec.key).endswith("|combined|emu|g2xD3(1,2)")
@@ -332,7 +332,7 @@ def test_decide_combined_key_and_measured_win(tmp_path):
 
 
 def test_decide_combined_modes_and_candidates(tmp_path):
-    embs = _fleet_embeddings()
+    embs = _tenant_embeddings()
     assert at.candidates("alltoall", "combined") == ("combined", "time_mux")
     ana = at.Autotuner(cache_path=tmp_path / "a.json", mode="analytic")
     d = ana.decide_combined("alltoall", embs, nbytes=1 << 20)

@@ -466,3 +466,95 @@ def test_multitenant_eviction_recombines_without_rederiving(monkeypatch):
             for h in e.device_map:
                 mt.dead.add(HOST.id_router(int(h)))
         mt.plan_eviction()
+
+
+# ----------------------------------------------- a tenant leaves the host
+def _solo_program(kind):
+    """The guest's program, derived and lowered here, apart from the
+    cluster's library."""
+    if kind == "alltoall":
+        return _a2a_prog()
+    if kind == "allreduce":
+        return lowering.lower(hc.allreduce_schedule(GUEST.sbh))
+    return lowering.lower(bc.depth3_schedule(GUEST.topo, GUEST.topo.id_router(0)))
+
+
+# kind -> (replay, device axes of its input, fill of the idle devices)
+_REPLAY = {
+    "alltoall": (REF.run_alltoall, (0, 1), 0),
+    "allreduce": (REF.run_allreduce, (0,), 9.25),
+    "broadcast": (REF.run_broadcast, (0,), -3.0),
+}
+
+
+@pytest.mark.parametrize("cause", ["fail", "release"])
+@pytest.mark.parametrize("kind", ["alltoall", "allreduce", "broadcast"])
+def test_survivor_replays_bit_exact_after_eviction(kind, cause):
+    """A tenant leaves, by a failed chip in its image or by ``release``:
+    the survivor's slice of the re-combined program's replay is, bit for
+    bit, its solo emulated program's replay, what the two-tenant program
+    gave it, and the guest-sized program's replay."""
+    from repro.train.fault_tolerance import MultiTenantCluster
+
+    mt = MultiTenantCluster(DeviceLayout(HOST))
+    for e in EMBS:
+        mt.admit(e)
+    both = mt.plan_eviction().programs[kind]
+    if cause == "fail":
+        mt.fail(int(EMBS[1].device_map[0]))
+        plan = mt.plan_eviction()
+    else:
+        plan = mt.release(1)
+    assert plan.surviving == (0,) and plan.evicted == (1,)
+    assert plan.embeddings == (EMBS[0],) and mt.tenants == [EMBS[0]]
+
+    replay, axes, fill = _REPLAY[kind]
+    solo = _solo_program(kind)
+    rng = np.random.default_rng(3)
+    shape = (solo.n, solo.n, 3) if kind == "alltoall" else (solo.n, 4)
+    xs = [rng.standard_normal(shape).astype(np.float32) for _ in EMBS]
+    alone = scatter_guests(xs[:1], EMBS[:1], axes=axes, fill=fill)
+
+    def survivor(x, program):
+        return extract_guest(replay(x, program), EMBS[0], axes=axes)
+
+    want = survivor(alone, emulate(solo, EMBS[0]))
+    np.testing.assert_array_equal(survivor(alone, plan.programs[kind]), want)
+    np.testing.assert_array_equal(
+        survivor(scatter_guests(xs, EMBS, axes=axes, fill=fill), both), want)
+    np.testing.assert_array_equal(replay(xs[0], solo), want)
+
+
+def test_release_last_tenant_leaves_an_empty_plan():
+    """Releasing every tenant is legal: the last plan keeps no survivor
+    and no program, and the freed routers seat a tenant again."""
+    from repro.train.fault_tolerance import MultiTenantCluster
+
+    mt = MultiTenantCluster(DeviceLayout(HOST))
+    for e in EMBS:
+        mt.admit(e)
+    first = mt.release(0)
+    assert first.surviving == (1,) and first.embeddings == (EMBS[1],)
+    assert set(first.programs) == {"alltoall", "allreduce", "broadcast"}
+    last = mt.release(0)  # positions are re-counted after each release
+    assert last.surviving == () and last.evicted == (0,)
+    assert last.embeddings == () and last.index_maps == ()
+    assert last.programs == {}
+    assert mt.tenants == []
+    assert mt.admit(EMBS[1]) == 0
+
+
+def test_release_rejects_an_out_of_range_index():
+    """``release`` takes a seated tenant's position; any other index
+    raises ``IndexError`` and unseats nobody."""
+    from repro.train.fault_tolerance import MultiTenantCluster
+
+    mt = MultiTenantCluster(DeviceLayout(HOST))
+    for e in EMBS:
+        mt.admit(e)
+    for bad in (len(EMBS), -1):
+        with pytest.raises(IndexError, match="out of range"):
+            mt.release(bad)
+    assert mt.tenants == list(EMBS)
+    with pytest.raises(IndexError, match="0 seated"):
+        MultiTenantCluster(DeviceLayout(HOST)).release(0)
