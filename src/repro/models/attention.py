@@ -264,14 +264,16 @@ def mla_train(params, x, cfg, positions, use_kernel=True):
     return o.reshape(B, S, H * m.v_head_dim) @ params["wo"]
 
 
-def mla_decode(params, x, cache, layer, cfg, position):
+def mla_decode(params, x, cache, layer, cfg, position, live=None):
     """Absorbed MLA decode. x: (B, 1, d); cache: {'latent': (L, B, max_seq,
     w)}, every layer's rows ``[c_kv | k_rope | 0]`` (``mla_cache_init``),
-    written and read as ``gqa_decode``'s. The key up-projection W_UK is absorbed into the
-    query (``q_nope . W_UK``), so scores are taken against the latent rows
-    themselves, and the value up-projection W_UV applies to the
-    attention's latent output: no cached position is expanded to per-head
-    keys or values. Returns (out, cache)."""
+    written and read as ``gqa_decode``'s; ``live`` (B,) bool, the rows
+    that hold a request (None: all), the only ones the kernel reads. The
+    key up-projection W_UK is absorbed into the query (``q_nope . W_UK``),
+    so scores are taken against the latent rows themselves, and the value
+    up-projection W_UV applies to the attention's latent output: no cached
+    position is expanded to per-head keys or values. Returns (out,
+    cache)."""
     m = cfg.mla
     B = x.shape[0]
     H, r, nope = cfg.n_heads, m.kv_lora_rank, m.qk_nope_head_dim
@@ -292,7 +294,7 @@ def mla_decode(params, x, cache, layer, cfg, position):
     with jax.named_scope("attn.decode"):
         if default_impl() == "pallas":
             # reads the layer's blocks where they lie (XLA copies a layer out)
-            o = mla_decode_attention(q, latent, layer, pos_b, r=r, scale=mla_scale(cfg))
+            o = mla_decode_attention(q, latent, layer, pos_b, live, r=r, scale=mla_scale(cfg))
         else:
             o = _mla_decode_xla(q, latent[layer], pos_b, r, mla_scale(cfg))
     with jax.named_scope("attn.absorb"):

@@ -195,7 +195,7 @@ def decode_step(params, cache, batch, position, cfg: ModelConfig, unroll: bool =
 
     batch: {'token': (B,)} or {'embed': (B, d)} (+ mrope positions), and
     optionally 'live' (B,) bool: the rows that hold a request, the only
-    ones the MoE layers route (all when absent).
+    ones the MoE layers route and MLA attention reads (all when absent).
     Returns (logits (B, vocab), new_cache); the new cache's
     ``experts_run`` adds the held experts this step's MoE layers ran.
     """
@@ -205,13 +205,14 @@ def decode_step(params, cache, batch, position, cfg: ModelConfig, unroll: bool =
         x = L.embed_apply(params["embed"], batch["token"][:, None]).astype(
             jnp.dtype(cfg.compute_dtype)
         )
-    mrope = batch.get("mrope_positions")
+    mrope, live = batch.get("mrope_positions"), batch.get("live")
     new_cache = dict(cache)
     if cfg.first_dense_layers:
         def pre_fn(carry, inputs):
             x, c = carry
             member, i = inputs
-            x, c, _ = T.member_decode(member, x, c, i, cfg, "attn", "mlp", position, mrope)
+            x, c, _ = T.member_decode(member, x, c, i, cfg, "attn", "mlp", position, mrope,
+                                      live)
             return (x, c), None
         carry = (x, cache["prefix"][0])
         if unroll:
@@ -224,7 +225,7 @@ def decode_step(params, cache, batch, position, cfg: ModelConfig, unroll: bool =
         x, pc = carry
         new_cache["prefix"] = (pc,)
     x, nsc, experts_run = T.stack_decode(params["stack"], x, cache["stack"], cfg, position,
-                                         mrope, unroll, batch.get("live"))
+                                         mrope, unroll, live)
     new_cache["stack"] = nsc
     new_cache["experts_run"] = cache["experts_run"] + experts_run
     h = T.norm_fn(cfg)(params["final_norm"], x)

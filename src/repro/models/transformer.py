@@ -109,13 +109,13 @@ def member_decode(params, x, cache, layer, cfg, mixer, ffn, position, mrope_posi
     MoE member's expert weights are every layer's, stacked
     (``stack_decode`` keeps them out of the layer loop's slices), and its
     routing skips the tokens of rows where ``live`` is False
-    (``moe.moe_decode``). Returns (x, cache, the held experts run: 0 but
-    for an MoE member)."""
+    (``moe.moe_decode``), as MLA attention skips their cached rows.
+    Returns (x, cache, the held experts run: 0 but for an MoE member)."""
     norm = norm_fn(cfg)
     h = norm(params["norm1"], x)
     if mixer == "attn":
         if cfg.attention == "mla":
-            mx, cache = A.mla_decode(params["mixer"], h, cache, layer, cfg, position)
+            mx, cache = A.mla_decode(params["mixer"], h, cache, layer, cfg, position, live)
         else:
             mx, cache = A.gqa_decode(
                 params["mixer"], h, cache, layer, cfg, position, mrope_positions

@@ -152,3 +152,43 @@ def test_expert_shares_add_up_to_the_uncut_layer(dispatch):
     with jax.default_matmul_precision("highest"):
         want = np.asarray(R.moe(x.reshape(-1, SMOKE.d_model), w, SMOKE))
     np.testing.assert_allclose(whole.reshape(want.shape), want, rtol=0, atol=1e-5)
+
+
+def test_free_slot_is_not_read_on_the_kernel_path(params, monkeypatch):
+    """The decode step as a TPU runs it (the MLA and expert kernels, here
+    interpreted), with the last slot free at a stale position two blocks
+    deep: the live slots get the logits of the step with every slot live,
+    and keep them, to the bit, when every cached row of the free slot, in
+    the dense layer and in the stack, is NaN; the free slot's own logits
+    stay finite, so neither layer's kernel read its rows."""
+    import functools
+
+    from repro.kernels.flash_attention import mla_decode
+    from repro.kernels.moe import expert_ffn
+
+    monkeypatch.setattr(A, "default_impl", lambda: "pallas")
+    monkeypatch.setattr(A, "mla_decode_attention",
+                        functools.partial(mla_decode.mla_decode_attention, interpret=True))
+    monkeypatch.setattr(MOE, "default_impl", lambda: "pallas")
+    monkeypatch.setattr(MOE, "expert_ffn", functools.partial(expert_ffn.expert_ffn,
+                                                             interpret=True))
+    B, S = 3, 256
+    rng = np.random.default_rng(6)
+    w = CFG.mla.kv_lora_rank + CFG.mla.qk_rope_head_dim
+
+    def rows(a):  # a cache of earlier tokens' rows, zero past each row's width
+        return a if a.ndim < 4 else jnp.asarray(
+            rng.standard_normal(a.shape), a.dtype).at[..., w:].set(0.0)
+
+    cache = jax.tree.map(rows, M.init_cache(CFG, B, S, dtype=jnp.float32))
+    step = jax.jit(lambda c, live: M.decode_step(
+        params, c, {"token": jnp.asarray([3, 7, 11], jnp.int32), "live": live},
+        jnp.asarray([5, 130, S - 1], jnp.int32), CFG)[0])
+    live = jnp.asarray([True, True, False])
+    every = np.asarray(step(cache, jnp.ones(B, bool)))
+    got = np.asarray(step(cache, live))
+    np.testing.assert_allclose(got[:2], every[:2], rtol=0, atol=1e-6 * np.abs(every).max())
+    nan = jax.tree.map(lambda a: a if a.ndim < 4 else a.at[:, 2].set(jnp.nan), cache)
+    poisoned = np.asarray(step(nan, live))
+    np.testing.assert_array_equal(poisoned[:2], got[:2])
+    assert np.isfinite(poisoned[2]).all()

@@ -149,8 +149,23 @@ def test_decode_attention_vs_ref(window, block):
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2 ** -8, rtol=0)
 
 
-@pytest.mark.parametrize("block", [512, 128])
-def test_mla_decode_attention_vs_jnp(block):
+# (block, positions, live) over 6 slots of 2048 positions: every slot live
+# at the edges of blocks of 512 and of 128 (the default); edges of 128 and
+# 256; free slots whose stale positions sit at 2047 and at block edges,
+# first, last and between live ones; every slot free
+_MLA_POS = [0, 511, 512, 1023, 1024, 2047]
+MLA_DECODE_CASES = {
+    "512": (512, _MLA_POS, None),
+    "128": (128, _MLA_POS, None),
+    "block_edges": (None, [127, 128, 255, 256, 1, 2046], None),
+    "free_stale": (None, [2047, 127, 300, 128, 511, 5], [0, 0, 1, 0, 1, 1]),
+    "free_between": (512, [40, 2047, 1023, 512, 700, 2047], [1, 0, 1, 0, 1, 0]),
+    "all_free": (None, [2047, 127, 128, 511, 512, 0], [0, 0, 0, 0, 0, 0]),
+}
+
+
+@pytest.mark.parametrize("case", list(MLA_DECODE_CASES))
+def test_mla_decode_attention_vs_jnp(case):
     """The MLA decode kernel (interpret mode) against the jnp form a CPU
     takes (``attention._mla_decode_xla``), over one layer of a stacked
     latent cache of 2048 positions with DeepSeek-V2-Lite's row (512 + 64,
@@ -160,15 +175,18 @@ def test_mla_decode_attention_vs_jnp(block):
     ``2 sign(q_0)``, which it would attend to instead were the position
     past the slot's read: a block read one short or one long moves head 0
     by whole units. Inputs lie on the bf16 grid, so only the kernel's bf16
-    probabilities round: values |v| <= 2 bound the difference by 2**-7."""
+    probabilities round: values |v| <= 2 bound the difference by 2**-7.
+    A free slot returns zeros, and the live slots' outputs stay the same
+    bits when every row of the free slots, in every layer, is NaN."""
     from repro.kernels.flash_attention.mla_decode import mla_decode_attention
     from repro.models.attention import _mla_decode_xla
 
+    block, pos, live = MLA_DECODE_CASES[case]
     rng = np.random.default_rng(5)
     L, B, S, r, dr, w, H = 2, 6, 2048, 512, 64, 640, 16
     grid = lambda *shape: jnp.asarray(rng.uniform(-1, 1, shape), jnp.bfloat16).astype(jnp.float32)
     q = grid(B, H, w).at[..., r + dr:].set(0.0)
-    pos = np.asarray([0, 511, 512, 1023, 1024, 2047], np.int32)
+    pos = np.asarray(pos, np.int32)
     latent = np.array(grid(L, B, S, w))
     loud = np.sign(np.asarray(q[:, 0]))
     for b, p in enumerate(pos):
@@ -178,11 +196,35 @@ def test_mla_decode_attention_vs_jnp(block):
     latent = jnp.asarray(latent).at[..., r + dr:].set(0.0)
     scale = (r + dr) ** -0.5
     pos = jnp.asarray(pos)
-    got = mla_decode_attention(q, latent, 1, pos, r=r, scale=scale, block=block,
-                               interpret=True)
+    kw = {} if block is None else {"block": block}
+    attend = lambda lat, live: mla_decode_attention(q, lat, 1, pos, live, r=r, scale=scale,
+                                                    interpret=True, **kw)
+    got = attend(latent, None if live is None else jnp.asarray(live, bool))
     want = _mla_decode_xla(q, latent[1], pos, r, scale)
     np.testing.assert_allclose(np.asarray(want)[:, 0], loud[:, :r], atol=0.05)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2 ** -7, rtol=0)
+    on = np.ones(B, bool) if live is None else np.asarray(live, bool)
+    np.testing.assert_allclose(np.asarray(got)[on], np.asarray(want)[on], atol=2 ** -7, rtol=0)
+    assert not np.asarray(got)[~on].any()
+    if live is not None:
+        garbage = latent.at[:, ~on].set(jnp.nan)
+        np.testing.assert_array_equal(np.asarray(attend(garbage, jnp.asarray(on))),
+                                      np.asarray(got))
+
+
+@pytest.mark.parametrize("block, bs", [(None, 128), (512, 512)])
+def test_mla_decode_rows_read(block, bs):
+    """The rows the MLA kernel copies in: none for a free slot, whatever its
+    stale position; ``(p // bs + 1) * bs`` for a live slot at ``p``; every
+    slot live without a mask."""
+    from repro.kernels.flash_attention.mla_decode import rows_read
+
+    kw = {} if block is None else {"block": block}
+    pos = np.asarray([0, 127, 128, 511, 512, 2047, 2047, 300], np.int32)
+    live = np.asarray([1, 1, 1, 1, 1, 1, 0, 0], bool)
+    want = (pos // bs + 1) * bs
+    np.testing.assert_array_equal(np.asarray(rows_read(pos, live, 2048, **kw)),
+                                  np.where(live, want, 0))
+    np.testing.assert_array_equal(np.asarray(rows_read(pos, None, 2048, **kw)), want)
 
 
 # ------------------------------------------------------------- expert FFN

@@ -228,18 +228,20 @@ def test_serve_decode_step_updates_cache_in_place(one_chip, monkeypatch):
 def test_mla_decode_attention_compiles(one_chip):
     """The MLA decode kernel over the longgen cell's stacked f32 latent
     cache (DeepSeek-V2-Lite: 27 layers, 32 slots of 2048 positions, rows
-    of 512 + 64 padded to 640, 16 heads): no copy and no scratch in HBM,
-    and the kernel's instruction is named for the trace."""
+    of 512 + 64 padded to 640, 16 heads), with the live-slot mask: no copy
+    and no scratch in HBM, and the kernel's instruction is named for the
+    trace."""
 
     from repro.kernels.flash_attention.mla_decode import mla_decode_attention
 
     L, B, S, r, w, H = 27, 32, 2048, 512, 640, 16
     latent = jax.ShapeDtypeStruct((L, B, S, w), jnp.float32, sharding=one_chip)
-    compiled = jax.jit(lambda q, c, layer, pos: mla_decode_attention(
-        q, c, layer, pos, r=r, scale=0.1, interpret=False)).lower(
+    compiled = jax.jit(lambda q, c, layer, pos, live: mla_decode_attention(
+        q, c, layer, pos, live, r=r, scale=0.1, interpret=False)).lower(
         jax.ShapeDtypeStruct((B, H, w), jnp.float32, sharding=one_chip), latent,
         jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip),
-        jax.ShapeDtypeStruct((B,), jnp.int32, sharding=one_chip)).compile()
+        jax.ShapeDtypeStruct((B,), jnp.int32, sharding=one_chip),
+        jax.ShapeDtypeStruct((B,), jnp.bool_, sharding=one_chip)).compile()
     calls = [line for line in compiled.as_text().splitlines()
              if 'custom_call_target="tpu_custom_call"' in line]
     assert calls and all(re.match(r"\s*(ROOT )?%mla_decode", c) for c in calls)
